@@ -90,7 +90,8 @@ def _edge_aggregate_rows(quick: bool = False):
     """CSR edge aggregation on the gaia (N=11) FEMNIST CNN config."""
     from repro.core.delay import FEMNIST
     from repro.fl import dpasgd, flat as flatmod
-    from repro.kernels.gossip_combine.kernel import _pick_block_t
+    from repro.kernels.gossip_combine.kernel import (_pick_block_t,
+                                                     block_vmem_bytes)
     from repro.kernels.gossip_combine.ops import csr_sort, edge_aggregate
     from repro.models.small import SMALL_MODELS
     from repro.networks.zoo import get_network
@@ -113,20 +114,21 @@ def _edge_aggregate_rows(quick: bool = False):
     dst_sorted = jnp.asarray(plan.dst[order])
     rows = []
 
-    # --- interpret-mode parity: kernel == segment_sum reference ---
+    # --- parity: kernel == segment_sum reference ---
     w = jax.random.normal(key, (n, t_par), jnp.float32)
     buf = jax.random.normal(jax.random.PRNGKey(1), (e2, t_par), jnp.float32)
-    out = edge_aggregate(w, buf, coeffs, jnp.asarray(row_ptr), diag,
-                         interpret=True)
+    # (compiled on a TPU, the Pallas interpreter elsewhere)
+    out = edge_aggregate(w, buf, coeffs, jnp.asarray(row_ptr), diag)
     ref = jax.jit(lambda w_, b_: edge_aggregate_ref(
         w_, b_, coeffs, dst_sorted, diag))(w, buf)
     maxdiff = float(jnp.max(jnp.abs(out - ref)))
     match = bool(np.allclose(np.asarray(out), np.asarray(ref),
                              rtol=1e-5, atol=1e-5))
-    block_t = _pick_block_t(t_par, e2, 65536)
-    vmem = (e2 + 2) * block_t * 4
+    block_t = _pick_block_t(t_par, n, e2, 65536)
+    vmem = block_vmem_bytes(n, e2, block_t)
     rows.append((f"kernel/edge_aggregate/parity_T{t_par}", 0.0,
-                 f"interpret_matches_segment_sum={match} "
+                 f"platform={jax.default_backend()} "
+                 f"matches_segment_sum={match} "
                  f"maxdiff={maxdiff:.2e} block_t={block_t} "
                  f"vmem_tile_bytes={vmem} (<16MB: {vmem < 16e6})"))
 
@@ -177,56 +179,73 @@ def _edge_aggregate_rows(quick: bool = False):
 def _mesh_cycle_rows(quick: bool = False):
     """Sharded vs single-device whole-cycle scaling (fl/mesh.py).
 
-    Each shard count needs its own XLA device count, which is fixed at
-    backend init — so every (network, D) point runs in a CHILD process
-    with XLA_FLAGS=--xla_force_host_platform_device_count=D
-    (benchmarks/mesh_cycle_child.py). The child parity-asserts the
-    sharded cycle against the single-device oracle before timing; a row
-    with parity=False is a correctness failure, not a slow result.
+    Every point parity-checks the sharded cycle against the
+    single-device oracle before timing (benchmarks/mesh_cycle_child.py);
+    a row with parity=False is a correctness failure, not a slow result.
 
-    On this container the 8 "devices" are threads of nproc physical
-    cores, so whole-cycle time does NOT drop with D — the run is
-    CPU-bound and the derived field records cpu_cores for the roofline
-    explanation (DESIGN.md §16): on real hardware the shard-local terms
-    (local SGD + segment_sum over per-shard rows) divide by D while
-    only the halo bytes stay on the wire.
+    On a TPU host the points run in THIS process over `jax.devices()`:
+    the process holds the chips, so a child could not reach them. On the
+    CPU backend each shard count needs its own forced host-device count,
+    fixed at backend init, so every (network, D) point runs in a CHILD
+    process with XLA_FLAGS=--xla_force_host_platform_device_count=D.
+    Those host devices are threads of nproc physical cores, so
+    whole-cycle time does NOT drop with D; the derived field records
+    `platform=cpu` and cpu_cores so the rows are never read as chip
+    numbers (DESIGN.md §16).
     """
     import os
+
+    points = ([("gaia", d) for d in (1, 2)] if quick else
+              [(net, d) for net in ("gaia", "wan64")
+               for d in (1, 2, 4, 8)])
+    on_cpu = jax.default_backend() == "cpu"
+    if not on_cpu:
+        points = [(net, d) for net, d in points if d <= jax.device_count()]
+    cores = os.cpu_count()
+    rows, base_us = [], {}
+    for net, d in points:
+        name = f"kernel/fl_mesh_cycle/{net}_d{d}"
+        if on_cpu:
+            out = _mesh_cycle_child(net, d)
+            if isinstance(out, str):
+                rows.append((name, 0.0, f"FAILED: {out}"))
+                continue
+            where = (f"platform=cpu cpu_cores={cores} (host devices share "
+                     f"{cores} core(s): CPU-bound, see DESIGN.md §16)")
+        else:
+            from benchmarks.mesh_cycle_child import measure
+            out = measure(net, d, 2)
+            where = (f"platform={out['platform']} "
+                     f"device_kind={out['device_kind']}")
+        base_us.setdefault(net, out["us_per_cycle"])
+        speedup = base_us[net] / max(out["us_per_cycle"], 1e-9)
+        rows.append((
+            name, out["us_per_cycle"],
+            f"N={out['num_silos']} T={out['t']} "
+            f"rounds={out['rounds_per_cycle']} parity={out['parity']} "
+            f"halo_rows={out['halo_rows']} speedup_vs_d1={speedup:.2f}x "
+            f"{where}"))
+    return rows
+
+
+def _mesh_cycle_child(net: str, d: int):
+    """One CPU-backend point in a child process; its JSON dict, or the
+    tail of its stderr when it failed."""
     import subprocess
     import sys
 
     child = pathlib.Path(__file__).parent / "mesh_cycle_child.py"
     src = pathlib.Path(__file__).parent.parent / "src"
-    points = ([("gaia", d) for d in (1, 2)] if quick else
-              [(net, d) for net in ("gaia", "wan64")
-               for d in (1, 2, 4, 8)])
-    cores = os.cpu_count()
-    rows, base_us = [], {}
-    for net, d in points:
-        # JAX_PLATFORMS=cpu: the child must not probe accelerator
-        # plugins — this bench process already holds the device (libtpu
-        # serializes on a lockfile and the child would sleep forever).
-        env = {"PYTHONPATH": str(src), "PATH": "/usr/bin:/bin",
-               "JAX_PLATFORMS": "cpu",
-               "XLA_FLAGS": f"--xla_force_host_platform_device_count={d}"}
-        r = subprocess.run(
-            [sys.executable, str(child), net, str(d), "2"],
-            capture_output=True, text=True, timeout=1500, env=env)
-        if r.returncode != 0:
-            rows.append((f"kernel/fl_mesh_cycle/{net}_d{d}", 0.0,
-                         f"FAILED: {r.stderr[-200:]}"))
-            continue
-        out = json.loads(r.stdout.strip().splitlines()[-1])
-        base_us.setdefault(net, out["us_per_cycle"])
-        speedup = base_us[net] / max(out["us_per_cycle"], 1e-9)
-        rows.append((
-            f"kernel/fl_mesh_cycle/{net}_d{d}", out["us_per_cycle"],
-            f"N={out['num_silos']} T={out['t']} "
-            f"rounds={out['rounds_per_cycle']} parity={out['parity']} "
-            f"halo_rows={out['halo_rows']} speedup_vs_d1={speedup:.2f}x "
-            f"cpu_cores={cores} (host devices share {cores} core(s): "
-            f"CPU-bound, see DESIGN.md §16 roofline)"))
-    return rows
+    # JAX_PLATFORMS=cpu: the child must not probe accelerator plugins
+    # (only the CPU backend takes this route)
+    env = {"PYTHONPATH": str(src), "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={d}"}
+    r = subprocess.run([sys.executable, str(child), net, str(d), "2"],
+                       capture_output=True, text=True, timeout=1500, env=env)
+    if r.returncode != 0:
+        return r.stderr[-200:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
 
 
 def _merge_json(rows, path: str = "BENCH_kernels.json") -> None:

@@ -1,11 +1,12 @@
-"""Child process for kernel_bench's fl_mesh_cycle rows.
+"""One fl_mesh_cycle point for kernel_bench: `measure(net, D, iters)`.
 
-Launched once per (network, shard count) with
-XLA_FLAGS=--xla_force_host_platform_device_count=<D> in the
-environment (device count is fixed at backend init, so each D needs its
-own process). Parity-asserts one sharded cycle against the
-single-device oracle, times the sharded whole-cycle dispatch, and
-prints one JSON line on stdout.
+Parity-checks one sharded cycle against the single-device oracle, then
+times the sharded whole-cycle dispatch. On a TPU host kernel_bench calls
+`measure` in its own process over `jax.devices()`. On the CPU backend
+each shard count needs its own forced host-device count, fixed at
+backend init, so kernel_bench runs this file as a child per point with
+XLA_FLAGS=--xla_force_host_platform_device_count=<D>; it then prints
+one JSON line on stdout.
 
     python benchmarks/mesh_cycle_child.py <network> <num_shards> [iters]
 """
@@ -35,9 +36,7 @@ def _loss(p, batch):
     return jnp.mean((batch["x"] @ p["w"] + p["b"]) ** 2)
 
 
-def main():
-    net_name, d = sys.argv[1], int(sys.argv[2])
-    iters = int(sys.argv[3]) if len(sys.argv) > 3 else 3
+def measure(net_name: str, d: int, iters: int = 3) -> dict:
     assert jax.device_count() >= d, (jax.device_count(), d)
 
     net = get_network(net_name)
@@ -57,9 +56,11 @@ def main():
     state = flmesh.init_mesh_state(_init, opt, mrt, key)
     cycle = rtmod.make_cycle_fn(mrt, loss_fn=_loss, opt=opt)
 
-    # parity vs the single-device oracle, full cycle, before timing
+    # parity vs the single-device oracle, full cycle, before timing (the
+    # oracle aggregates by segment_sum, as every shard does)
     s1 = rtmod.init_flat_state(_init, opt, rt, key)
-    c1 = rtmod.make_cycle_fn(rt, loss_fn=_loss, opt=opt)
+    c1 = rtmod.make_cycle_fn(rt, loss_fn=_loss, opt=opt,
+                             aggregator="reference")
     s1, _ = c1(s1, *args)
     sm, _ = cycle(state, *args)
     flat = flmesh.gather_flat_state(mrt, sm)
@@ -74,12 +75,19 @@ def main():
     jax.block_until_ready(sm)
     us = (time.perf_counter() - t0) / iters * 1e6
 
-    print(json.dumps({
+    dev = jax.devices()[0]
+    return {
         "net": net_name, "num_silos": n, "d": d, "t": rt.spec.size,
         "rounds_per_cycle": r, "us_per_cycle": round(us, 1),
         "parity": bool(parity), "halo_rows": mrt.halo.halo_rows,
         "trace_count": cycle.trace_count["count"],
-    }))
+        "platform": dev.platform, "device_kind": dev.device_kind,
+    }
+
+
+def main():
+    iters = int(sys.argv[3]) if len(sys.argv) > 3 else 3
+    print(json.dumps(measure(sys.argv[1], int(sys.argv[2]), iters)))
 
 
 if __name__ == "__main__":

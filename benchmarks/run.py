@@ -49,6 +49,9 @@ def main() -> None:
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (ablation_noniid, faults_bench, fig5_convergence,
                             kernel_bench, obs_bench, population_bench,
                             serving_bench, sim_bench, table1_cycle_time,

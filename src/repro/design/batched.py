@@ -519,7 +519,7 @@ class CandidateScorer:
             if self._dev is None:
                 import jax
                 import jax.numpy as jnp
-                with jax.experimental.enable_x64():
+                with jax.enable_x64(True):
                     self._dev = (jnp.asarray(self.d0, jnp.float64),
                                  jnp.asarray(self.pair_comp, jnp.float64))
             taus = timing_jax.grid_recurrence_taus(

@@ -41,8 +41,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import axis_size as _axis_size  # shared compat shim
-
 Params = Any
 
 
@@ -86,7 +84,7 @@ def gossip_ring_ppermute(params: Params, buffers: dict, *,
 
     Returns (new_params, new_buffers).
     """
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     left_perm, right_perm = _ring_perms(n)
 

@@ -320,7 +320,6 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
     n, per = mrt.num_silos, mrt.per_rows
     rows_padded = mrt.mspec.rows_padded
     spec = mrt.spec
-    smap = meshmod.shard_map_fn()
     plan_specs = fl_plan_specs(axis=axis)
     row_spec = P(axis, None)
 
@@ -474,8 +473,8 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
         out_specs = (row_spec, os_spec, row_spec, P())
         if ms is not None:
             out_specs = out_specs + (P(),)  # metrics replicated
-        fn = smap(body, mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         out = fn(state.w, state.opt_state, state.buffers,
                  batches_p, strong_p, coeffs_p, diag_p,
                  dst_local, src_global, gather_idx,
@@ -487,11 +486,7 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
         return FlatFLState(w, os2, buf), losses, mets
 
     jitted = jax.jit(cycle, donate_argnums=(0,) if donate else ())
-
-    def run(state, batches, strong, coeffs, diag):
-        return jitted(state, batches, strong, coeffs, diag)
-
-    run.trace_count = counter
+    jitted.trace_count = counter
     if ms is not None:
-        run.metric_columns = ms.columns(n, mesh=True)
-    return run
+        jitted.metric_columns = ms.columns(n, mesh=True)
+    return jitted

@@ -267,14 +267,10 @@ def make_cycle_fn(rt: FlatRuntime, *, loss_fn, opt, lr_scale=1.0,
         return FlatFLState(w, os_, buf), losses, mets
 
     jitted = jax.jit(cycle, donate_argnums=(0,) if donate else ())
-
-    def run(state, batches, strong, coeffs, diag):
-        return jitted(state, batches, strong, coeffs, diag)
-
-    run.trace_count = counter
+    jitted.trace_count = counter
     if ms is not None:
-        run.metric_columns = ms.columns(rt.num_silos)
-    return run
+        jitted.metric_columns = ms.columns(rt.num_silos)
+    return jitted
 
 
 def unpack_params(rt: FlatRuntime, state: FlatFLState) -> Params:

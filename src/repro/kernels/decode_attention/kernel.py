@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -40,7 +41,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     q = q_ref[0].astype(jnp.float32)      # (g, hd)
     k = k_ref[0].astype(jnp.float32)      # (block_s, hd)
     v = v_ref[0].astype(jnp.float32)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     pos = si * block_s + jax.lax.broadcasted_iota(jnp.int32, (block_s, 1), 0)
     ok = (pos < length) & (pos < seq)     # (block_s, 1)
@@ -87,31 +88,26 @@ def decode_attention(q, k, v, lengths, *, block_s: int = 512,
     vr = v.reshape(b * hkv, s, hd)
     lens = jnp.repeat(lengths.astype(jnp.int32), hkv)  # (B*Hkv,)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, block_s=block_s, seq=s),
+    # lengths ride in SMEM as a scalar prefetch: a (1,) VMEM block per
+    # grid row is not a tile the TPU lowering accepts
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b * hkv, ns),
         in_specs=[
-            pl.BlockSpec((1,), lambda h, si: (h,)),
-            pl.BlockSpec((1, g, hd), lambda h, si: (h, 0, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda h, si: (h, si, 0)),
-            pl.BlockSpec((1, block_s, hd), lambda h, si: (h, si, 0)),
+            pl.BlockSpec((1, g, hd), lambda h, si, _: (h, 0, 0)),
+            pl.BlockSpec((1, block_s, hd), lambda h, si, _: (h, si, 0)),
+            pl.BlockSpec((1, block_s, hd), lambda h, si, _: (h, si, 0)),
         ],
-        out_specs=pl.BlockSpec((1, g, hd), lambda h, si: (h, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, hd), lambda h, si, _: (h, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),    # running max
+                        pltpu.VMEM((g, 1), jnp.float32),    # denominator
+                        pltpu.VMEM((g, hd), jnp.float32)],  # accumulator
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, block_s=block_s, seq=s),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, g, hd), q.dtype),
-        scratch_shapes=_scratch(g, hd),
         interpret=interpret,
     )(lens, qr, kr, vr)
     return out.reshape(b, hq, hd)
 
-
-def _scratch(g: int, hd: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        mem = pltpu.VMEM
-    except Exception:  # pragma: no cover
-        mem = None
-    if mem is None:
-        return [pl.MemorySpace.ANY((g, 1), jnp.float32)] * 2 + \
-            [pl.MemorySpace.ANY((g, hd), jnp.float32)]
-    return [mem((g, 1), jnp.float32), mem((g, 1), jnp.float32),
-            mem((g, hd), jnp.float32)]

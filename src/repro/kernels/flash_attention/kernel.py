@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -145,20 +146,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def _scratch(group: int, block_q: int, hd: int):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        mem = pltpu.VMEM
-    except Exception:  # pragma: no cover
-        mem = pl.MemorySpace.ANY
-
-    def make(shape):
-        try:
-            return mem(shape, jnp.float32)
-        except TypeError:  # pragma: no cover
-            return pl.MemorySpace.ANY(shape, jnp.float32)
-
     return [
-        make((group, block_q)),      # m: running max
-        make((group, block_q)),      # l: running denominator
-        make((group, block_q, hd)),  # acc: unnormalized output
+        pltpu.VMEM((group, block_q), jnp.float32),      # m: running max
+        pltpu.VMEM((group, block_q), jnp.float32),      # l: denominator
+        pltpu.VMEM((group, block_q, hd), jnp.float32),  # acc: unnormalized
     ]

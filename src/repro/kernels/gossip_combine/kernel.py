@@ -13,17 +13,19 @@ VMEM exactly once per aggregation:
     out[i] = diag[i] * w[i] + sum_{e in row i} coeff[e] * buf[e]
     over ALL N destination silos of a round plan at once. Edges arrive
     sorted by destination with `row_ptr` offsets (classic CSR); the
-    grid is (T/block_t, N) with the destination axis innermost, so the
-    (2E, block_t) buffer slab is fetched once per tile and every
-    destination's incoming rows are reduced from VMEM in fp32 —
-    one HBM pass over the edge buffers per aggregation, replacing a
-    per-leaf `segment_sum` stack (dozens of small HBM-bound ops).
+    grid walks T in `block_t` tiles, each step holding the full-height
+    (N, block_t) w/out tiles and the (2E, block_t) buffer slab in VMEM,
+    and the kernel loops over destinations and their CSR rows inside
+    the tile in fp32 — one HBM pass over the edge buffers per
+    aggregation, replacing a per-leaf `segment_sum` stack (dozens of
+    small HBM-bound ops).
     Rows may be empty (isolated destinations aggregate only their own
     diag-scaled weights — the paper's isolated-node mechanism).
 
 Accumulation order matches `jax.ops.segment_sum` over dst-sorted edges
 (ascending edge index within a row, `diag * w` added last), so the
-kernel is bit-for-bit fp32-equal to the reference lowering.
+kernel and the reference lowering differ by a few ulp at most, where a
+compiler contracts a multiply and an add into an FMA.
 """
 
 from __future__ import annotations
@@ -77,37 +79,73 @@ def gossip_combine(weights: jax.Array, coeffs: jax.Array, *,
 # ---------------------------------------------------------------------------
 
 
-def _edge_agg_kernel(row_ptr_ref, coeff_ref, diag_ref, w_ref, buf_ref, o_ref):
-    i = pl.program_id(1)                         # destination silo
-    start = row_ptr_ref[i]
-    end = row_ptr_ref[i + 1]
+def _edge_agg_kernel(row_ptr_ref, coeff_ref, diag_ref, w_ref, buf_ref, o_ref,
+                     *, lanes: int):
+    # w_ref/o_ref hold every destination's (N, block_t) tile and buf_ref
+    # the (2E, block_t) slab, so no block has a 1-row second-minor dim.
+    # Destinations and their CSR rows are walked in-kernel, `lanes`
+    # columns at a time so a row's accumulator stays in a few vregs.
+    n = o_ref.shape[0]
 
-    def body(e, acc):
-        row = buf_ref[pl.ds(e, 1), :].astype(jnp.float32)   # (1, block_t)
-        return acc + coeff_ref[e] * row
+    def chunk(c, carry):
+        cols = pl.ds(pl.multiple_of(c * lanes, lanes), lanes)
 
-    acc = jax.lax.fori_loop(start, end, body,
-                            jnp.zeros(o_ref.shape, jnp.float32))
-    own = diag_ref[i] * w_ref[...].astype(jnp.float32)
-    o_ref[...] = (own + acc).astype(o_ref.dtype)
+        def dest(i, carry):
+            def edge(e, acc):
+                row = buf_ref[pl.ds(e, 1), cols].astype(jnp.float32)
+                return acc + coeff_ref[e] * row
+
+            acc = jax.lax.fori_loop(row_ptr_ref[i], row_ptr_ref[i + 1], edge,
+                                    jnp.zeros((1, lanes), jnp.float32))
+            own = diag_ref[i] * w_ref[pl.ds(i, 1), cols].astype(jnp.float32)
+            o_ref[pl.ds(i, 1), cols] = (own + acc).astype(o_ref.dtype)
+            return carry
+
+        return jax.lax.fori_loop(0, n, dest, carry)
+
+    jax.lax.fori_loop(0, o_ref.shape[1] // lanes, chunk, 0)
 
 
-def _pick_block_t(t: int, e2: int, block_t: int,
-                  vmem_budget: int = 8 << 20) -> int:
-    """Largest lane-aligned tile whose (2E + 2) rows fit the budget."""
+#: Narrowest column chunk Mosaic loads from a dynamic row: a (1, 128)
+#: row is laid out in (8, 128) tiles and refused ("dynamic load with
+#: unaligned indices"); from 256 lanes up it packs into whole vregs.
+_MIN_LANES = 256
+
+
+def _padded_rows(r: int) -> int:
+    return -(-r // 8) * 8          # VMEM tiles are 8 sublanes high
+
+
+def block_vmem_bytes(n: int, e2: int, block_t: int) -> int:
+    """VMEM one grid step holds: Pallas double-buffers every operand, so
+    two copies each of the w, out and buf tiles — 2 * (2*N + 2E) rows,
+    each padded to 8 sublanes, of `block_t` fp32 columns."""
+    return 2 * (2 * _padded_rows(n) + _padded_rows(e2)) * 4 * block_t
+
+
+def _pick_block_t(t: int, n: int, e2: int, block_t: int,
+                  vmem_budget: int = 12 << 20) -> int:
+    """Largest tile of `_MIN_LANES` multiples whose blocks fit the budget
+    (`block_vmem_bytes`; the budget sits under v5e's 16 MiB default
+    scoped-VMEM limit)."""
+    col_bytes = block_vmem_bytes(n, e2, 1)
     block_t = min(block_t, t)
-    while block_t > 128 and (e2 + 2) * block_t * 4 > vmem_budget:
+    while block_t > _MIN_LANES and col_bytes * block_t > vmem_budget:
         block_t //= 2
-    if t >= 128:
-        block_t = max(block_t // 128 * 128, 128)
-    if (e2 + 2) * block_t * 4 > (16 << 20):
+    block_t = max(block_t // _MIN_LANES * _MIN_LANES, _MIN_LANES)
+    if col_bytes * block_t > vmem_budget:
         # even the minimum tile cannot hold the (2E, block_t) slab
         raise ValueError(
-            f"edge_aggregate: 2E={e2} directed edges need "
-            f"{(e2 + 2) * block_t * 4 / 2**20:.1f} MB of VMEM at the "
-            f"minimum tile; use the segment_sum reference lowering for "
-            f"graphs this dense")
+            f"edge_aggregate: N={n} silos and 2E={e2} directed edges need "
+            f"{col_bytes * block_t / 2**20:.1f} MiB of VMEM at the minimum "
+            f"tile; use the segment_sum reference lowering for graphs "
+            f"this dense")
     return block_t
+
+
+def _pick_lanes(block_t: int) -> int:
+    """Inner column chunk: the widest of 1024/512/256 dividing the tile."""
+    return next(c for c in (1024, 512, 256) if block_t % c == 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -128,22 +166,29 @@ def edge_aggregate(w: jax.Array, buf: jax.Array, coeffs: jax.Array,
     if e2 == 0:
         return (diag[:, None].astype(jnp.float32) *
                 w.astype(jnp.float32)).astype(w.dtype)
-    block_t = _pick_block_t(t, e2, block_t)
+    if t < _MIN_LANES:
+        # models this small pad up to one chunk (the pad is inert: its
+        # columns are computed and sliced off)
+        pad = ((0, 0), (0, _MIN_LANES - t))
+        return edge_aggregate(jnp.pad(w, pad), jnp.pad(buf, pad), coeffs,
+                              row_ptr, diag, block_t=block_t,
+                              interpret=interpret)[:, :t]
+    block_t = _pick_block_t(t, n, e2, block_t)
     # Ragged grid: Pallas masks the tail tile itself (reads beyond T are
     # don't-cares that stay in the tail columns elementwise; writes are
     # clipped) — no host-side jnp.pad, so the per-round scan never makes
     # an HBM copy of the (2E, T) buffers just to round T up.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(pl.cdiv(t, block_t), n),
+        grid=(pl.cdiv(t, block_t),),
         in_specs=[
-            pl.BlockSpec((1, block_t), lambda j, i, *_: (i, j)),    # w row
-            pl.BlockSpec((e2, block_t), lambda j, i, *_: (0, j)),   # buf slab
+            pl.BlockSpec((n, block_t), lambda j, *_: (0, j)),    # w tile
+            pl.BlockSpec((e2, block_t), lambda j, *_: (0, j)),   # buf slab
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda j, i, *_: (i, j)),
+        out_specs=pl.BlockSpec((n, block_t), lambda j, *_: (0, j)),
     )
     out = pl.pallas_call(
-        _edge_agg_kernel,
+        functools.partial(_edge_agg_kernel, lanes=_pick_lanes(block_t)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, t), w.dtype),
         interpret=interpret,

@@ -3,8 +3,9 @@
 `gossip_combine_ref`: out = sum_k a[k] * w[k] (fixed-K stacked form).
 `edge_aggregate_ref`: the DPASGD aggregation over an arbitrary directed
 edge list via `segment_sum` — exactly the lowering `fl_round_step` uses
-per leaf, applied to one flat buffer. The CSR kernel must match this
-bit-for-bit in fp32 when its edges are dst-sorted with a stable sort.
+per leaf, applied to one flat buffer. With its edges dst-sorted by a
+stable sort, the CSR kernel sums in the same order and matches this to
+a few fp32 ulp (FMA contraction).
 """
 
 from __future__ import annotations

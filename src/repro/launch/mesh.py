@@ -17,10 +17,6 @@ state. The dry-run process sets xla_force_host_platform_device_count
 BEFORE any jax import (see dryrun.py); ordinary processes (tests,
 benches) see 1 device and build 1-shard meshes unless launched with the
 flag themselves.
-
-This module is also the one home of the jax-version compat shims for
-shard_map programs (`axis_size`, `shard_map_fn`) — fl/gossip.py and the
-mp_scripts used to carry private copies.
 """
 
 from __future__ import annotations
@@ -28,48 +24,6 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
-
-
-# ---------------------------------------------------------------------------
-# jax-version compat (one shared copy; see ISSUE 8 satellite)
-# ---------------------------------------------------------------------------
-
-
-def axis_size(axis: str) -> int:
-    """Static mesh-axis size inside shard_map, across jax versions."""
-    import jax
-
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    import jax.core as _core  # 0.4.x: the frame IS the size
-    return int(_core.axis_frame(axis))
-
-
-def shard_map_fn():
-    """The shard_map entrypoint, across jax versions (>=0.5 exports it
-    at top level; 0.4.x keeps it under jax.experimental)."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-def shard_map_partial_auto(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map with only `manual_axes` manual; other mesh axes stay
-
-    auto. Bridges the kwarg rename (new: axis_names/check_vma; 0.4.x:
-    auto/check_rep) so production scripts run on either jax."""
-    sm = shard_map_fn()
-    manual = frozenset(manual_axes)
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False, axis_names=manual)
-    except TypeError:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False,
-                  auto=frozenset(mesh.axis_names) - manual)
 
 
 # ---------------------------------------------------------------------------

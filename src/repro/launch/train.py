@@ -309,6 +309,8 @@ def main():
                          "--set seed=3 --set batch_size=8")
     args = ap.parse_args()
     from repro.config_cli import apply_overrides
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     mesh = args.mesh
     if mesh is not None and mesh != "auto":
         mesh = int(mesh)

@@ -35,15 +35,22 @@ def sgd(lr: float, momentum: float = 0.0,
         if weight_decay:
             grads = _tmap(lambda g, p: g + weight_decay * p.astype(g.dtype),
                           grads, params)
+        # `pin_dtype` makes both paths bit-identical between this
+        # per-leaf layout and the flat (N, T) layout (see fl/flat.py).
+        # It pins every mul-feeding-add site to rounded values, which
+        # LLVM would otherwise FMA-contract differently per layout. It
+        # also pins the gradient operand of the momentum add: when a
+        # leaf's gradient ends in a scatter-add (an embedding lookup's
+        # transpose), XLA rewrites `x + scatter(0, u)` into
+        # `scatter(x, u)` in one layout and not the other, which
+        # reorders the float additions.
         if momentum == 0.0:
-            new = _tmap(lambda p, g: p - (lr_t * g).astype(p.dtype),
-                        params, grads)
+            new = _tmap(
+                lambda p, g: p - pin_dtype(lr_t * g, step).astype(p.dtype),
+                params, grads)
             return new, {"step": step}
-        # `pin_dtype` pins the mul-feeding-add sites to rounded values so
-        # the momentum path is bit-identical between this per-leaf
-        # layout and the flat (N, T) layout (see fl/flat.py) —
-        # otherwise LLVM FMA-contracts the two layouts differently.
-        mu = _tmap(lambda m, g: pin_dtype(momentum * m, step) + g,
+        mu = _tmap(lambda m, g: pin_dtype(momentum * m, step) +
+                   pin_dtype(g, step),
                    state["mu"], grads)
         new = _tmap(lambda p, m: p - pin_dtype(lr_t * m, step).astype(p.dtype),
                     params, mu)
@@ -77,12 +84,13 @@ def flat_sgd(lr: float, momentum: float = 0.0,
         lr_t = lr * lr_scale
         if weight_decay:
             g = g + weight_decay * w.astype(g.dtype)
+        # same pinned sites as `sgd` — the two paths are bit-for-bit
+        # equal in every layout (tests/test_flat_runtime.py holds them
+        # exactly equal, not allclose).
         if momentum == 0.0:
-            return w - (lr_t * g).astype(w.dtype), {"step": step}
-        # same pinned sites as `sgd` — the two momentum paths are
-        # bit-for-bit equal in every layout (tests/test_flat_runtime.py
-        # holds them exactly equal, not allclose).
-        mu = pin_dtype(momentum * state["mu"], step) + g
+            return (w - pin_dtype(lr_t * g, step).astype(w.dtype),
+                    {"step": step})
+        mu = pin_dtype(momentum * state["mu"], step) + pin_dtype(g, step)
         return (w - pin_dtype(lr_t * mu, step).astype(w.dtype),
                 {"step": step, "mu": mu})
 

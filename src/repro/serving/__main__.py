@@ -54,6 +54,8 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", default=None, metavar="OUT.json")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="fl_serve_")
     out = {"ckpt_dir": ckpt_dir}
     if not args.skip_train:

@@ -14,10 +14,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
-try:  # jax >= 0.5 exports it at top level
-    from jax import shard_map  # noqa: E402
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from repro.core.consensus import metropolis_weights  # noqa: E402
 from repro.core.graph import make_graph  # noqa: E402
@@ -111,7 +108,7 @@ def main():
                   {"left": {"w": P("silo")}, "right": {"w": P("silo")}},
                   None, None, None),
         out_specs={"w": P("silo")},
-        check_rep=False)  # pallas_call has no replication rule
+        check_vma=False)  # pallas_call has no replication rule
     def ring_step_kernel(p, bufs, cs_, cl_, cr_):
         local = {"w": p["w"][0]}
         lb = {"w": bufs["left"]["w"][0]}

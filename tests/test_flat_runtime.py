@@ -351,7 +351,7 @@ def test_pin_dtype_f64_and_passthrough():
     assert flatmod.pin_f32 is flatmod.pin_dtype
     ints = jnp.arange(5, dtype=jnp.int32)
     assert flatmod.pin_dtype(ints, jnp.int32(1)) is ints
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(np.random.default_rng(1).normal(size=64),
                         jnp.float64)
         y = jax.jit(flatmod.pin_dtype)(x, jnp.int32(2 ** 15 + 7))

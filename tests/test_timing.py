@@ -494,6 +494,29 @@ def test_sweep_batched_equals_per_cell():
         assert b.report == o.report
 
 
+def test_reports_agree_rtol():
+    """Bitwise by default. Under the emulated-f64 bound, float fields may
+    differ by rounding but not by a wrong branch, and integer
+    statistics stay exact."""
+    import dataclasses
+
+    from repro.core import sweep
+
+    rep = simulate("multigraph", GAIA, FEMNIST, num_rounds=400, t=5)
+    rtol = sweep.EMULATED_F64_RTOL
+    rounding = dataclasses.replace(
+        rep, mean_cycle_ms=rep.mean_cycle_ms * (1 + 4e-16),
+        total_time_s=float(np.nextafter(rep.total_time_s, np.inf)))
+    wrong = dataclasses.replace(rep, mean_cycle_ms=rep.mean_cycle_ms * 1.01)
+    count = dataclasses.replace(
+        rep, states_with_isolated=rep.states_with_isolated + 1)
+    assert sweep.reports_agree(rep, rep)
+    assert not sweep.reports_agree(rounding, rep)
+    assert sweep.reports_agree(rounding, rep, rtol)
+    assert not sweep.reports_agree(wrong, rep, rtol)
+    assert not sweep.reports_agree(count, rep, rtol)
+
+
 def test_sweep_driver_quick_grid():
     from repro.core import sweep
 
