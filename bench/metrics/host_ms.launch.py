@@ -1,0 +1,18 @@
+"""Host time per round in `run_fl`'s `launch` spans, in ms.
+
+From the start of each dispatch until the compiled cycle's call
+returns (the plan slices' copies and the launch): the `launch` spans
+inside the untraced window, summed, over its rounds. Nothing is read
+where the program records no such span.
+"""
+
+SPAN = "launch"
+
+
+def read(ctx):
+    w = ctx.window
+    inside = [b - a for name, a, b in w.spans
+              if name == SPAN and a >= w.t0_s and b <= w.t1_s]
+    if not inside:
+        return None
+    return sum(inside) / w.rounds * 1e3

@@ -1,0 +1,17 @@
+"""Host time per round in `run_fl`'s `sample` spans, in ms.
+
+Drawing every silo's batches for a dispatch and stacking them: the
+`sample` spans inside the untraced window, summed, over its rounds.
+Nothing is read where the program records no such span.
+"""
+
+SPAN = "sample"
+
+
+def read(ctx):
+    w = ctx.window
+    inside = [b - a for name, a, b in w.spans
+              if name == SPAN and a >= w.t0_s and b <= w.t1_s]
+    if not inside:
+        return None
+    return sum(inside) / w.rounds * 1e3

@@ -44,7 +44,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.fl import flat as flatmod
 from repro.fl import gossip
-from repro.fl.runtime import FlatFLState, FlatRuntime
+from repro.fl.runtime import (SCOPE_AGGREGATE, SCOPE_LOCAL_SGD,
+                              SCOPE_REFRESH, FlatFLState, FlatRuntime)
 from repro.kernels.gossip_combine.ref import edge_aggregate_ref
 from repro.launch import mesh as meshmod
 from repro.launch.sharding import fl_plan_specs
@@ -380,7 +381,8 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
                                 * row_mask)
                 return (w, os_), (loss, gsq_u)
 
-            (w, os_), ys = jax.lax.scan(local_step, (w, os_), batch)
+            with jax.named_scope(SCOPE_LOCAL_SGD):
+                (w, os_), ys = jax.lax.scan(local_step, (w, os_), batch)
             if ms is None or not ms.grad_norm:
                 losses = ys
             else:
@@ -389,12 +391,15 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
             # cross-shard fetch of this shard's edge SOURCE rows, then
             # shard-local refresh + aggregation (pad edges dropped by
             # segment_sum's out-of-range semantics)
-            if gossip_backend == "halo":
-                rows = gossip.csr_gather_halo(w, sends, perms, gath, axis)
-            else:
-                rows = gossip.csr_gather_all(w, src_g, axis)
-            buf = jnp.where(strong_r[:, None], rows, buf)
-            w = edge_aggregate_ref(w, buf, coeffs_r, dst_l, diag_r)
+            with jax.named_scope(SCOPE_REFRESH):
+                if gossip_backend == "halo":
+                    rows = gossip.csr_gather_halo(w, sends, perms, gath,
+                                                  axis)
+                else:
+                    rows = gossip.csr_gather_all(w, src_g, axis)
+                buf = jnp.where(strong_r[:, None], rows, buf)
+            with jax.named_scope(SCOPE_AGGREGATE):
+                w = edge_aggregate_ref(w, buf, coeffs_r, dst_l, diag_r)
 
             # Reported loss: mean over REAL silos only, at the oracle's
             # (u, N) reduce shape. The training STATE stays bit-exact;
@@ -402,9 +407,11 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
             # rounds because XLA's reduce-to-scalar emitter vectorizes
             # differently inside the two loop programs — a reporting
             # artifact, tolerated in tests (DESIGN.md §16).
-            la = jax.lax.all_gather(losses, axis, axis=1, tiled=True)
+            with jax.named_scope(SCOPE_LOCAL_SGD):
+                la = jax.lax.all_gather(losses, axis, axis=1, tiled=True)
+                loss = jnp.mean(la[:, :n])
             if ms is None:
-                return (w, os_, buf), jnp.mean(la[:, :n])
+                return (w, os_, buf), loss
 
             vals = {}
             if ms.grad_norm:
@@ -428,7 +435,7 @@ def make_mesh_cycle_fn(mrt: MeshRuntime, *, loss_fn, opt, lr_scale=1.0,
                 vals["gossip_bytes"] = n_strong * row_bytes
                 vals["fabric_bytes"] = jnp.float32(fabric_bytes)
             row = obsmet.assemble_row(ms, vals)
-            return (w, os_, buf, age), (jnp.mean(la[:, :n]), row)
+            return (w, os_, buf, age), (loss, row)
 
         carry = (w, os_, buf)
         if ms is not None:

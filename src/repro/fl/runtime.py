@@ -37,6 +37,15 @@ from repro.kernels.gossip_combine.ref import (dense_edge_aggregate,
 
 Params = Any
 
+# `jax.named_scope`s of a round's three stages (metadata only: the ops
+# and their numbers do not change). Every op of the round body lies in
+# one of them, so a profiler trace splits a round's device time by
+# scope through each op's HLO `op_name`; the mesh runtime uses the same
+# names.
+SCOPE_LOCAL_SGD = "fl.local_sgd"   # the local-step scan, the loss mean
+SCOPE_REFRESH = "fl.refresh"       # strong-edge buffer refresh
+SCOPE_AGGREGATE = "fl.aggregate"   # edge_aggregate (or its twins)
+
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
@@ -211,26 +220,32 @@ def make_cycle_fn(rt: FlatRuntime, *, loss_fn, opt, lr_scale=1.0,
             gsq_u = jnp.sum(jnp.square(grads.astype(jnp.float32)))
             return (w, os_), (loss, gsq_u)
 
-        (w, os_), ys = jax.lax.scan(local_step, (w, os_), batches)
+        with jax.named_scope(SCOPE_LOCAL_SGD):
+            (w, os_), ys = jax.lax.scan(local_step, (w, os_), batches)
         if ms is None or not ms.grad_norm:
             losses = ys
         else:
             losses, gsq_u = ys
 
         # buffer refresh on strong edges (fresh w_src), else keep stale
-        buf = jnp.where(strong_r[:, None], w[src_sorted], buf)
+        with jax.named_scope(SCOPE_REFRESH):
+            buf = jnp.where(strong_r[:, None], w[src_sorted], buf)
 
         # aggregation: w_i <- diag_i * w_i + sum_{e in row i} c_e * buf_e
-        if aggregator == "kernel":
-            w = gossip_ops.edge_aggregate(w, buf, coeffs_r, row_ptr, diag_r)
-        elif aggregator == "dense":
-            w = dense_edge_aggregate(w, buf,
-                                     coeffs_r.reshape(w.shape[0], deg),
-                                     diag_r)
-        else:
-            w = edge_aggregate_ref(w, buf, coeffs_r, dst_sorted, diag_r)
+        with jax.named_scope(SCOPE_AGGREGATE):
+            if aggregator == "kernel":
+                w = gossip_ops.edge_aggregate(w, buf, coeffs_r, row_ptr,
+                                              diag_r)
+            elif aggregator == "dense":
+                w = dense_edge_aggregate(w, buf,
+                                         coeffs_r.reshape(w.shape[0], deg),
+                                         diag_r)
+            else:
+                w = edge_aggregate_ref(w, buf, coeffs_r, dst_sorted, diag_r)
+        with jax.named_scope(SCOPE_LOCAL_SGD):
+            loss = jnp.mean(losses)
         if ms is None:
-            return (w, os_, buf), jnp.mean(losses)
+            return (w, os_, buf), loss
 
         vals = {}
         if ms.grad_norm:
@@ -249,7 +264,7 @@ def make_cycle_fn(rt: FlatRuntime, *, loss_fn, opt, lr_scale=1.0,
         if ms.traffic:
             vals["gossip_bytes"] = n_strong * row_bytes
         row = obsmet.assemble_row(ms, vals)
-        return (w, os_, buf, age), (jnp.mean(losses), row)
+        return (w, os_, buf, age), (loss, row)
 
     def cycle(state, batches, strong, coeffs, diag):
         counter["count"] += 1

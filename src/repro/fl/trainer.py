@@ -136,6 +136,25 @@ def _sample_round(data, n: int, cfg: FLConfig, rng) -> tuple[np.ndarray,
 
 
 def run_fl(cfg: FLConfig) -> FLResult:
+    """Train `cfg` end to end; returns losses, evals and the WAN axis.
+
+    With `cfg.trace` set (flat runtime), the loop records host spans
+    (`obs.TraceRecorder.host_span`, DESIGN.md §17), per dispatch:
+
+        sample            draw each silo's batches, stack the chunk
+        copy              host->device copy of the batches (`bytes`)
+        compile+dispatch  the first dispatch; `dispatch` every later one
+          launch          plan-slice copies and the compiled cycle's call
+          sync            the loss (and metrics) sync to the host
+        eval              every `eval_every` rounds
+        checkpoint        when `ckpt_dir` is set
+
+    `launch` and `sync` nest in the dispatch span (their `parent`); the
+    others are top level. Each span is also a profiler annotation, and
+    the compiled cycle splits its ops into the `fl.local_sgd`,
+    `fl.refresh` and `fl.aggregate` scopes (fl/runtime.py), so a
+    profile of the run puts host phases and device work on one clock.
+    """
     wl = WORKLOADS[_DATASET_WL[cfg.dataset]]
     net = get_network(cfg.network)
     if cfg.remove_strategy != "none" and cfg.remove_silos > 0:
@@ -232,6 +251,8 @@ def run_fl(cfg: FLConfig) -> FLResult:
                     loss_tail=[float(x) for x in round_losses[-8:]],
                     eval_accs=[float(x) for x in eval_accs[-4:]])
 
+        span = (recorder.host_span if recorder is not None
+                else lambda name, **args: contextlib.nullcontext())
         k = 0
         while k < cfg.rounds:
             # advance a whole cycle per dispatch, splitting at eval
@@ -243,46 +264,39 @@ def run_fl(cfg: FLConfig) -> FLResult:
                 next_stop = min(next_stop,
                                 (k // cfg.ckpt_every + 1) * cfg.ckpt_every)
             chunk = min(r_cycle, next_stop - k)
-            per_round = [_sample_round(data, n, cfg, rng)
-                         for _ in range(chunk)]
-            batches = {"x": jnp.asarray(np.stack([x for x, _ in per_round])),
-                       "y": jnp.asarray(np.stack([y for _, y in per_round]))}
+            with span("sample", rounds=chunk):
+                per_round = [_sample_round(data, n, cfg, rng)
+                             for _ in range(chunk)]
+                xs = np.stack([x for x, _ in per_round])
+                ys = np.stack([y for _, y in per_round])
+            with span("copy", bytes=xs.nbytes + ys.nbytes):
+                batches = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
             pks = [(k + j) % r_cycle for j in range(chunk)]
-            if recorder is not None:
-                span = recorder.host_span(
-                    "compile+dispatch" if k == 0 else "dispatch",
-                    start_round=k, rounds=chunk)
-            else:
-                span = contextlib.nullcontext()
-            with span:
-                out = cycle_fn(state, batches,
-                               jnp.asarray(rt.strong[pks]),
-                               jnp.asarray(rt.coeffs[pks]),
-                               jnp.asarray(rt.diag[pks]))
-                if cfg.metrics is not None:
-                    state, losses, mets = out
-                    metrics_chunks.append(np.asarray(mets))
-                else:
-                    state, losses = out
-                losses = np.asarray(losses)
+            with span("compile+dispatch" if k == 0 else "dispatch",
+                      start_round=k, rounds=chunk):
+                with span("launch", rounds=chunk):
+                    out = cycle_fn(state, batches,
+                                   jnp.asarray(rt.strong[pks]),
+                                   jnp.asarray(rt.coeffs[pks]),
+                                   jnp.asarray(rt.diag[pks]))
+                with span("sync"):
+                    if cfg.metrics is not None:
+                        state, losses, mets = out
+                        metrics_chunks.append(np.asarray(mets))
+                    else:
+                        state, losses = out
+                    losses = np.asarray(losses)
             round_losses.extend(float(x) for x in losses)
             k += chunk
             if k % cfg.eval_every == 0 or k == cfg.rounds:
-                if recorder is not None:
-                    span = recorder.host_span("eval", round=k)
-                else:
-                    span = contextlib.nullcontext()
-                with span:
+                with span("eval", round=k):
                     acc = float(acc_fn(eval_params_fn(get_w(state))))
                 eval_rounds.append(k)
                 eval_accs.append(acc)
             if ckpt_mgr is not None and (
                     k == cfg.rounds or
                     (cfg.ckpt_every > 0 and k % cfg.ckpt_every == 0)):
-                if recorder is not None:
-                    with recorder.host_span("checkpoint", round=k):
-                        emit_ckpt(k, state)
-                else:
+                with span("checkpoint", round=k):
                     emit_ckpt(k, state)
     elif cfg.runtime == "legacy":
         if cfg.mesh is not None:

@@ -9,8 +9,11 @@ Three pieces, one contract:
     exact current program — provably inert.
   * `obs.trace`    — `TraceRecorder`: fuses three clocks (simulated
     time from `TimingPlan`/`FaultedSession`, host wall clock around
-    compile/dispatch, controller events) into one ordered event log
-    keyed on (round, silo).
+    the trainer's host phases, controller events) into one ordered
+    event log keyed on (round, silo). Host spans nest (each names its
+    `parent`) and are also `jax.profiler` annotations, so a profile
+    shows them on the device ops' clock; the compiled cycle's ops
+    carry the `fl.local_sgd` / `fl.refresh` / `fl.aggregate` scopes.
   * `obs.export`   — Chrome/Perfetto `trace_event` JSON + JSONL
     run-record, consumed by `benchmarks/obs_bench.py` and
     `python -m repro.obs`.

@@ -7,7 +7,8 @@ a phase `ph`: "X" complete spans (ts/dur, microseconds), "C" counters,
 
   pid 1  "simulated"   — one thread per silo (tid = silo), counter
                          tracks from the in-scan metrics
-  pid 2  "host"        — wall-clock compile/dispatch/eval spans
+  pid 2  "host"        — wall-clock host-phase spans (sample, copy,
+                         dispatch holding launch and sync, eval)
   pid 3  "controller"  — observe/replan/swap instants
   pid 4  "serving"     — request lifetimes, one thread per region
                          (only present when the fleet recorded any)

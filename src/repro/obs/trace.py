@@ -8,8 +8,13 @@ A `TraceRecorder` fuses three time sources into one ordered event log:
     Span ends reconcile EXACTLY with `cycle_times`: for every round,
     each silo's last span ends at the round's tau (tests/test_obs.py).
   * **host wall clock** — `host_span(...)` context manager around
-    compile/dispatch/eval boundaries in `fl/trainer.py` and
-    `design/evaluate.py`, measured from the recorder's epoch.
+    the host phases of `fl/trainer.py` (sample, copy, launch, sync,
+    dispatch, eval, checkpoint) and `design/evaluate.py`, measured
+    from the recorder's epoch. Spans nest; each event names the span
+    it was opened in (`parent`). Each span is also a
+    `jax.profiler.TraceAnnotation` of the same name, so under a
+    profiler it lands on the xplane's host plane, on the clock of the
+    device ops.
   * **controller events** — instants (`observe`/`replan`/`swap`) from
     `design/controller.py`, anchored on the simulated clock at the
     segment boundary where they fire.
@@ -38,6 +43,7 @@ import dataclasses
 import time
 from typing import Any
 
+import jax
 import numpy as np
 
 
@@ -54,6 +60,7 @@ class TraceRecorder:
 
     def __post_init__(self):
         self._epoch = time.perf_counter()
+        self._open: list[str] = []    # names of the host spans open now
 
     # ---- host wall clock --------------------------------------------
     def host_now_ms(self) -> float:
@@ -61,14 +68,22 @@ class TraceRecorder:
 
     @contextlib.contextmanager
     def host_span(self, name: str, **args: Any):
-        """Wall-clock span around a compile/dispatch/eval boundary."""
+        """Wall-clock span around a host phase, recorded when it closes
+        with the name of the span it was opened in (`parent`, None at
+        the top). The body also runs inside a profiler annotation of
+        the same name; with no profiler running that is a flag check."""
+        parent = self._open[-1] if self._open else None
+        self._open.append(name)
         t0 = self.host_now_ms()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(name):
+                yield
         finally:
+            self._open.pop()
             self.host_events.append({
                 "clock": "host", "name": name, "t0_ms": t0,
-                "dur_ms": self.host_now_ms() - t0, "args": args})
+                "dur_ms": self.host_now_ms() - t0, "parent": parent,
+                "args": args})
 
     # ---- serving clock ----------------------------------------------
     def request_span(self, name: str, *, t0_ms: float, dur_ms: float,

@@ -1,6 +1,6 @@
 """Observability layer (obs/, DESIGN.md §17).
 
-Four contracts:
+Five contracts:
 
 * **inertness** — `metrics=None` compiles the EXACT pre-obs program:
   final state (w, opt state, edge buffers) bit-identical to metrics-on
@@ -16,13 +16,23 @@ Four contracts:
   the repo's BENCH_*.json files.
 * **zero-recompile** — a traced controller run across live schedule
   swaps still compiles its cycle exactly once.
+* **layer boundaries** — `run_fl`'s host spans nest as documented
+  (sample and copy before each dispatch, launch and sync inside it),
+  each is also a profiler annotation, and the compiled flat and mesh
+  cycles carry the `fl.local_sgd` / `fl.refresh` / `fl.aggregate`
+  scopes in their HLO `op_name` metadata.
 
 Like test_fl_mesh.py this file runs on however many devices the host
 exposes (1 in tier-1; the CI obs/fl-mesh jobs re-run with 8 forced
 host devices).
 """
 
+import glob
 import json
+import os
+import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -363,6 +373,166 @@ def test_run_fl_metrics_and_trace(tmp_path):
     assert any(e["name"] == "compile+dispatch" for e in host)
     counters = [e for e in obj["traceEvents"] if e["ph"] == "C"]
     assert {e["name"] for e in counters} >= {"grad_norm", "param_norm"}
+
+
+# ---------------------------------------------------------------------------
+# layer boundaries: host spans in run_fl, scopes in the compiled cycle
+# ---------------------------------------------------------------------------
+
+
+def test_run_fl_host_spans_per_dispatch(tmp_path):
+    from repro.fl.trainer import FLConfig, run_fl
+    import repro.obs as obs
+    kept = []
+
+    class Kept(obs.TraceRecorder):
+        def __post_init__(self):
+            super().__post_init__()
+            kept.append(self)
+
+    real, obs.TraceRecorder = obs.TraceRecorder, Kept
+    try:
+        run_fl(FLConfig(dataset="femnist", network="gaia", topology="ring",
+                        rounds=4, eval_every=2, samples_per_silo=8,
+                        batch_size=2, seed=2, trace=str(tmp_path / "t.json")))
+    finally:
+        obs.TraceRecorder = real
+    (rec,) = kept
+    ev = rec.host_events
+    end = lambda e: e["t0_ms"] + e["dur_ms"]
+    disp = [e for e in ev if e["name"] in ("compile+dispatch", "dispatch")]
+    assert [e["name"] for e in disp] == ["compile+dispatch"] + ["dispatch"] * 3
+    for name in ("sample", "copy", "launch", "sync"):
+        assert len([e for e in ev if e["name"] == name]) == len(disp), name
+    for d in disp:
+        # the jit call and the loss sync, in order, inside the dispatch
+        launch, sync = [e for e in ev if e["parent"] == d["name"]
+                        and d["t0_ms"] <= e["t0_ms"] and end(e) <= end(d)]
+        assert (launch["name"], sync["name"]) == ("launch", "sync")
+        assert end(launch) <= sync["t0_ms"]
+        assert launch["args"] == {"rounds": 1}
+    samples = [e for e in ev if e["name"] == "sample"]
+    copies = [e for e in ev if e["name"] == "copy"]
+    for s, c, d in zip(samples, copies, disp):
+        assert s["parent"] is None and c["parent"] is None
+        assert end(s) <= c["t0_ms"] and end(c) <= d["t0_ms"]
+        assert s["args"] == {"rounds": 1}
+        # one round of 11 silos x 2 images (28x28 f32) and int32 labels
+        assert c["args"] == {"bytes": 11 * 2 * (28 * 28 * 4 + 4)}
+    assert all(e["parent"] is None for e in ev if e["name"] == "eval")
+
+
+def test_host_span_is_a_profiler_annotation(tmp_path):
+    from jax.profiler import ProfileData
+    f = jax.jit(lambda x: jnp.sin(x) @ x)
+    x = jnp.ones((32, 32))
+    f(x).block_until_ready()
+    rec = TraceRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    with rec.host_span("dispatch", rounds=1):
+        with rec.host_span("launch"):
+            y = f(x)
+        with rec.host_span("sync"):
+            y.block_until_ready()
+    jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(e)
+    for name in ("dispatch", "launch", "sync"):
+        assert len(host.get(name, [])) == 1, name
+    (d,), (ln,), (sy,) = host["dispatch"], host["launch"], host["sync"]
+    assert d.start_ns <= ln.start_ns and sy.end_ns <= d.end_ns
+    assert [(e["name"], e["parent"]) for e in rec.host_events] == [
+        ("launch", "dispatch"), ("sync", "dispatch"), ("dispatch", None)]
+
+
+SCOPES = (rtmod.SCOPE_LOCAL_SGD, rtmod.SCOPE_REFRESH, rtmod.SCOPE_AGGREGATE)
+
+
+def _scope_ops(hlo_text: str) -> dict:
+    """Instructions of the compiled cycle per `fl.*` scope of their
+    `op_name` (a scope nested in another counts once, innermost)."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m:
+            found = re.findall(r"fl\.(?:local_sgd|refresh|aggregate)",
+                               m.group(1))
+            key = found[-1] if found else None
+            out[key] = out.get(key, 0) + 1
+    return out
+
+
+@pytest.mark.parametrize("aggregator", ["reference", "dense"])
+def test_flat_cycle_carries_scopes(gaia_setup, aggregator):
+    _, _, _, n, batches = gaia_setup
+    # the dense twin needs a uniform in-degree: the ring's
+    plan = (dpasgd.make_round_schedule("ring", get_network("gaia"),
+                                       FEMNIST)[0]
+            if aggregator == "dense" else gaia_setup[2])
+    key = jax.random.PRNGKey(0)
+    opt = flat_sgd(0.05)
+    rt = rtmod.make_flat_runtime(plan, jax.eval_shape(_toy_init, key), n)
+    st = rtmod.init_flat_state(_toy_init, opt, rt, key)
+    cyc = rtmod.make_cycle_fn(rt, loss_fn=_toy_loss, opt=opt,
+                              aggregator=aggregator)
+    r = min(batches.shape[0], rt.num_rounds_cycle)
+    ops = _scope_ops(cyc.lower(st, *_cycle_args(rt, batches[:r]))
+                     .compile().as_text())
+    for scope in SCOPES:
+        assert ops.get(scope, 0) > 0, (scope, ops)
+
+
+def test_mesh_cycle_carries_scopes():
+    """The sharded cycle, compiled over four forced CPU devices (a
+    subprocess: this process keeps the devices it started with)."""
+    code = f"""
+import jax, jax.numpy as jnp, re
+from repro.core.delay import FEMNIST
+from repro.core import timing
+from repro.fl import dpasgd, mesh as flmesh, runtime as rtmod
+from repro.networks.zoo import get_network
+from repro.optim import flat_sgd
+assert jax.device_count() == 4
+net = get_network("gaia")
+tp = timing.multigraph_timing_plan(net, FEMNIST, t=5)
+plan, _, _ = dpasgd.multigraph_plan(net, FEMNIST, t=5, tplan=tp)
+init = lambda k: {{"w": jax.random.normal(k, (8,))}}
+loss = lambda p, b: jnp.sum((p["w"] - b["t"]) ** 2)
+key = jax.random.PRNGKey(0)
+opt = flat_sgd(0.05)
+n = int(plan.diag.shape[1])
+rt = rtmod.make_flat_runtime(plan, jax.eval_shape(init, key), n)
+for backend in ("halo", "all_gather"):
+    mrt = flmesh.make_mesh_runtime(rt, 4)
+    st = flmesh.init_mesh_state(init, opt, mrt, key)
+    cyc = rtmod.make_cycle_fn(mrt, loss_fn=loss, opt=opt, gossip=backend)
+    txt = cyc.lower(st, {{"t": jnp.zeros((2, 1, n, 1, 8))}},
+                    jnp.asarray(rt.strong[:2]), jnp.asarray(rt.coeffs[:2]),
+                    jnp.asarray(rt.diag[:2])).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', txt)
+    for scope in {SCOPES!r}:
+        assert any(scope in s for s in names), (backend, scope)
+    # the cross-shard fetch of the source rows is part of the refresh
+    comm = [l for l in txt.splitlines() if re.search(
+        r"= [^ ]+ (collective-permute|all-gather)(-start)?\\(", l)]
+    fetch = [l for l in comm if "fl.refresh" in l]
+    assert fetch, (backend, comm[:4])
+    print(backend + "-scopes-ok")
+"""
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src),
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stdout + r.stderr
+    assert "halo-scopes-ok" in r.stdout and "all_gather-scopes-ok" \
+        in r.stdout, r.stdout
 
 
 def test_trainer_rejects_obs_on_legacy_runtime():

@@ -127,6 +127,12 @@ def test_flat_cycle_compiles(shape, monkeypatch):
     compiled = cycle.lower(state, batches, shape((r, e2), jnp.bool_),
                            shape((r, e2)), shape((r, n))).compile()
     _assert_kernel(compiled)
+    # the round's scopes reach the chip's program, the kernel under the
+    # aggregation's: a profile splits device time by them
+    text = compiled.as_text()
+    kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert all(flrt.SCOPE_AGGREGATE in ln for ln in kernel)
+    assert flrt.SCOPE_LOCAL_SGD in text and flrt.SCOPE_REFRESH in text
     mem = compiled.memory_analysis()
     # the state and edge buffers fit one v5e's 16 GB with room to spare
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
