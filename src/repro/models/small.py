@@ -51,18 +51,35 @@ def _conv_init(key, shape):  # (H, W, Cin, Cout)
     return jax.random.normal(key, shape) * np.sqrt(2.0 / fan_in)
 
 
-def _conv(x, w, stride=1):
-    """SAME conv via im2col + matmul.
+#: Fewest filter input channels lowered to the native convolution. Under
+#: the per-silo vmap a conv is feature-grouped, one group a silo; on a
+#: TPU v5e a 1-channel group sums in another order than an ungrouped
+#: conv (ulps of f32), which the next layer's bf16 operand rounding
+#: turns into a first-loss drift, while 32-channel groups match bit for
+#: bit. Narrower filters keep im2col + matmul.
+_NATIVE_MIN_CIN = 8
 
-    XLA CPU lowers the FILTER gradient of a conv with vmapped (per-silo)
-    filters catastrophically (~25x slower); expressed as pad/slice/dot
-    everything stays fast and vmap-friendly, which is what the stacked
-    N-silo FL simulation needs.
+
+def _conv(x, w, stride=1):
+    """SAME conv (pad (k-1)//2 before, k-1-(k-1)//2 after; ceil(H/stride)
+    out) in float32 at the default matmul precision.
+
+    A filter of `_NATIVE_MIN_CIN` or more input channels is one
+    `lax.conv_general_dilated`, a grouped conv under the per-silo vmap;
+    its gradients cost about what im2col's do on XLA:CPU and far less on
+    the TPU. Narrower filters (FEMNIST's first conv, the ResNet stem)
+    build im2col patches (pad, kh*kw strided slices, one concatenate)
+    and multiply them by the flattened filter.
     """
     kh, kw, cin, cout = w.shape
-    b, h, wdt, c = x.shape
     ph, pw = (kh - 1) // 2, (kw - 1) // 2
-    xp = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
+    pads = ((ph, kh - 1 - ph), (pw, kw - 1 - pw))
+    if cin >= _NATIVE_MIN_CIN:
+        return jax.lax.conv_general_dilated(
+            x, w, (stride, stride), pads,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+    b, h, wdt, c = x.shape
+    xp = jnp.pad(x, ((0, 0),) + pads + ((0, 0),))
     ho = -(-h // stride)
     wo = -(-wdt // stride)
     cols = []

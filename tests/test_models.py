@@ -281,3 +281,87 @@ def test_small_model_param_budgets():
         spec = SMALL_MODELS[name]
         n = param_count(spec.init(KEY))
         assert lo <= n <= hi, f"{name}: {n} params outside [{lo},{hi}]"
+
+
+def _conv_oracle(x, w, stride):
+    """SAME conv as a sum of one matmul per kernel tap: no patches, no
+    convolution primitive."""
+    kh, kw = w.shape[:2]
+    xp = jnp.pad(x, ((0, 0), ((kh - 1) // 2, kh - 1 - (kh - 1) // 2),
+                     ((kw - 1) // 2, kw - 1 - (kw - 1) // 2), (0, 0)))
+    ho, wo = -(-x.shape[1] // stride), -(-x.shape[2] // stride)
+    return sum(jnp.einsum("bhwc,co->bhwo",
+                          xp[:, i:i + stride * (ho - 1) + 1:stride,
+                             j:j + stride * (wo - 1) + 1:stride], w[i, j])
+               for i in range(kh) for j in range(kw))
+
+
+# every conv shape of the FEMNIST CNN and the ResNet: (kernel, cin, cout,
+# input side, stride) and the lowering `_conv` must pick for it
+_CONV_SHAPES = {
+    "femnist_c1": ((5, 1, 32, 28, 1), "im2col"),
+    "femnist_c2": ((5, 32, 64, 14, 1), "native"),
+    "resnet_stem": ((3, 3, 64, 32, 1), "im2col"),
+    "resnet_3x3": ((3, 64, 64, 32, 1), "native"),
+    "resnet_3x3_s2": ((3, 64, 128, 32, 2), "native"),
+    "resnet_3x3_512": ((3, 512, 512, 4, 1), "native"),
+    "resnet_proj_s2": ((1, 128, 256, 16, 2), "native"),
+}
+
+
+def _conv_paths(jaxpr) -> tuple[int, int]:
+    """(native convolutions, im2col convolutions) in a jaxpr: an im2col
+    conv pads its input once; the native one pads inside the primitive."""
+    def names(jx):
+        for e in jx.eqns:
+            yield e.primitive.name
+            for v in e.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    yield from names(sub)
+
+    found = list(names(jaxpr.jaxpr))
+    return found.count("conv_general_dilated"), found.count("pad")
+
+
+@pytest.mark.parametrize("case", sorted(_CONV_SHAPES))
+def test_conv_matches_oracle_per_silo(case):
+    """`_conv` under the per-silo vmap (3 silos, distinct filters) equals a
+    per-tap matmul oracle in the forward and both gradients, and takes the
+    lowering its input-channel count selects."""
+    from repro.models.small import _conv
+    (k, cin, cout, side, stride), path = _CONV_SHAPES[case]
+    kx, kw_, kc = jax.random.split(jax.random.PRNGKey(k * cin + stride), 3)
+    x = jax.random.normal(kx, (3, 2, side, side, cin))
+    w = jax.random.normal(kw_, (3, k, k, cin, cout)) / np.sqrt(k * k * cin)
+    ho = -(-side // stride)
+    ct = jax.random.normal(kc, (3, 2, ho, ho, cout))
+
+    def run(conv):
+        def one(x, w, ct):
+            out, vjp = jax.vjp(lambda x, w: conv(x, w, stride), x, w)
+            return (out,) + vjp(ct)
+        return jax.jit(jax.vmap(one))(x, w, ct)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = run(_conv), run(_conv_oracle)
+    for name, g, r in zip(("out", "dx", "dw"), got, want):
+        g, r = np.asarray(g), np.asarray(r)
+        assert g.shape == r.shape, name
+        err = np.max(np.abs(g - r)) / np.max(np.abs(r))
+        assert err < 1e-5, (name, err)
+    paths = _conv_paths(jax.make_jaxpr(jax.vmap(
+        lambda x, w: _conv(x, w, stride)))(x, w))
+    assert paths == ((1, 0) if path == "native" else (0, 1))
+
+
+@pytest.mark.parametrize("name,native,im2col", [
+    ("femnist_cnn", 1, 1),      # c2 native; c1 (1 input channel) im2col
+    ("inat_resnet", 19, 1),     # 16 block convs + 3 projections; the stem
+])
+def test_small_model_conv_paths(name, native, im2col):
+    spec = SMALL_MODELS[name]
+    params = jax.eval_shape(spec.init, KEY)
+    x = jax.ShapeDtypeStruct((2,) + spec.input_shape, jnp.float32)
+    assert _conv_paths(jax.make_jaxpr(spec.apply)(params, x)) == (native,
+                                                                 im2col)

@@ -133,6 +133,13 @@ def test_flat_cycle_compiles(shape, monkeypatch):
     kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert all(flrt.SCOPE_AGGREGATE in ln for ln in kernel)
     assert flrt.SCOPE_LOCAL_SGD in text and flrt.SCOPE_REFRESH in text
+    # the 32-channel conv is the chip's native convolution at the
+    # configured precision: no bf16 result, no (.., 14, 14, 800) patches
+    convs = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert [ln for ln in convs if "conv_general_dilated" in ln]
+    assert not [ln for ln in convs if "= bf16[" in ln]
+    assert not [ln for ln in text.splitlines()
+                if " concatenate(" in ln and ",14,14,800]" in ln]
     mem = compiled.memory_analysis()
     # the state and edge buffers fit one v5e's 16 GB with room to spare
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
