@@ -56,7 +56,9 @@ def _conv_init(key, shape):  # (H, W, Cin, Cout)
 #: TPU v5e a 1-channel group sums in another order than an ungrouped
 #: conv (ulps of f32), which the next layer's bf16 operand rounding
 #: turns into a first-loss drift, while 32-channel groups match bit for
-#: bit. Narrower filters keep im2col + matmul.
+#: bit. Narrower filters keep im2col + matmul: one patch-extraction conv
+#: (a constant 0/1 filter, so the silo axis folds into its batch and it
+#: stays ungrouped) feeds one dot with the learned filter.
 _NATIVE_MIN_CIN = 8
 
 
@@ -68,8 +70,12 @@ def _conv(x, w, stride=1):
     `lax.conv_general_dilated`, a grouped conv under the per-silo vmap;
     its gradients cost about what im2col's do on XLA:CPU and far less on
     the TPU. Narrower filters (FEMNIST's first conv, the ResNet stem)
-    build im2col patches (pad, kh*kw strided slices, one concatenate)
-    and multiply them by the flattened filter.
+    build im2col patches with `lax.conv_general_dilated_patches` at the
+    highest precision (each patch element is one input element times 1,
+    so the patches are the input itself) and multiply them by the
+    flattened filter at the default precision. The patches order their
+    features (c, di, dj), so the filter is flattened in that order; for
+    one input channel that is the tap order (di, dj).
     """
     kh, kw, cin, cout = w.shape
     ph, pw = (kh - 1) // 2, (kw - 1) // 2
@@ -78,20 +84,11 @@ def _conv(x, w, stride=1):
         return jax.lax.conv_general_dilated(
             x, w, (stride, stride), pads,
             dimension_numbers=("NHWC", "HWIO", "NHWC"))
-    b, h, wdt, c = x.shape
-    xp = jnp.pad(x, ((0, 0),) + pads + ((0, 0),))
-    ho = -(-h // stride)
-    wo = -(-wdt // stride)
-    cols = []
-    for di in range(kh):
-        for dj in range(kw):
-            sl = jax.lax.slice(
-                xp, (0, di, dj, 0),
-                (b, di + (ho - 1) * stride + 1, dj + (wo - 1) * stride + 1, c),
-                (1, stride, stride, 1))
-            cols.append(sl)
-    patches = jnp.concatenate(cols, axis=-1)  # (B, Ho, Wo, kh*kw*C)
-    return patches @ w.reshape(kh * kw * cin, cout)
+    patches = jax.lax.conv_general_dilated_patches(
+        x, (kh, kw), (stride, stride), pads,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)  # (B, Ho, Wo, C*kh*kw)
+    return patches @ w.transpose(2, 0, 1, 3).reshape(kh * kw * cin, cout)
 
 
 def femnist_cnn_init(key) -> Params:

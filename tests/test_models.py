@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.extend.core import Literal
 
 from repro.configs import ARCH_IDS, all_configs, get_config, reduce
 from repro.models import mamba2, transformer as tf
@@ -296,12 +297,31 @@ def _conv_oracle(x, w, stride):
                for i in range(kh) for j in range(kw))
 
 
-# every conv shape of the FEMNIST CNN and the ResNet: (kernel, cin, cout,
-# input side, stride) and the lowering `_conv` must pick for it
+def _im2col_slices(x, w, stride):
+    """The slice-and-concatenate im2col conv: pad, one strided slice a
+    kernel tap, the taps concatenated on the minor axis in (di, dj, c)
+    order, one matmul with the filter flattened in that order."""
+    kh, kw, cin, cout = w.shape
+    b, h, wdt, c = x.shape
+    xp = jnp.pad(x, ((0, 0), ((kh - 1) // 2, kh - 1 - (kh - 1) // 2),
+                     ((kw - 1) // 2, kw - 1 - (kw - 1) // 2), (0, 0)))
+    ho, wo = -(-h // stride), -(-wdt // stride)
+    cols = [jax.lax.slice(xp, (0, di, dj, 0),
+                          (b, di + (ho - 1) * stride + 1,
+                           dj + (wo - 1) * stride + 1, c),
+                          (1, stride, stride, 1))
+            for di in range(kh) for dj in range(kw)]
+    return jnp.concatenate(cols, axis=-1) @ w.reshape(kh * kw * cin, cout)
+
+
+# every conv shape of the FEMNIST CNN and the ResNet, and a strided
+# narrow one: (kernel, cin, cout, input side, stride) and the lowering
+# `_conv` must pick for it
 _CONV_SHAPES = {
     "femnist_c1": ((5, 1, 32, 28, 1), "im2col"),
     "femnist_c2": ((5, 32, 64, 14, 1), "native"),
     "resnet_stem": ((3, 3, 64, 32, 1), "im2col"),
+    "narrow_3x3_s2": ((3, 3, 64, 32, 2), "im2col"),
     "resnet_3x3": ((3, 64, 64, 32, 1), "native"),
     "resnet_3x3_s2": ((3, 64, 128, 32, 2), "native"),
     "resnet_3x3_512": ((3, 512, 512, 4, 1), "native"),
@@ -310,18 +330,39 @@ _CONV_SHAPES = {
 
 
 def _conv_paths(jaxpr) -> tuple[int, int]:
-    """(native convolutions, im2col convolutions) in a jaxpr: an im2col
-    conv pads its input once; the native one pads inside the primitive."""
-    def names(jx):
+    """(native convolutions, im2col convolutions) in a jaxpr. An im2col
+    conv extracts its patches with a convolution whose filter is a
+    constant (built from no input of the jaxpr) with one output feature
+    a (tap, input channel); a native conv's filter is the learned
+    weight."""
+    found = {"native": 0, "im2col": 0}
+
+    def walk(jx, const_in):
+        const = {v for v, c in zip(jx.invars, const_in) if c}
+        const |= set(jx.constvars)
+
+        def is_const(v):
+            return isinstance(v, Literal) or v in const
+
         for e in jx.eqns:
-            yield e.primitive.name
+            ins = [is_const(v) for v in e.invars]
+            if e.primitive.name == "conv_general_dilated":
+                rhs = e.invars[1].aval.shape
+                spec = e.params["dimension_numbers"].rhs_spec
+                taps = int(np.prod([rhs[d] for d in spec[2:]]))
+                groups = e.params["feature_group_count"]
+                patch = ins[1] and rhs[spec[0]] == groups * rhs[spec[1]] * taps
+                found["im2col" if patch else "native"] += 1
             for v in e.params.values():
                 sub = getattr(v, "jaxpr", v)
                 if hasattr(sub, "eqns"):
-                    yield from names(sub)
+                    walk(sub, ins if len(ins) == len(sub.invars)
+                         else [False] * len(sub.invars))
+            if all(ins):
+                const |= set(e.outvars)
 
-    found = list(names(jaxpr.jaxpr))
-    return found.count("conv_general_dilated"), found.count("pad")
+    walk(jaxpr.jaxpr, [False] * len(jaxpr.jaxpr.invars))
+    return found["native"], found["im2col"]
 
 
 @pytest.mark.parametrize("case", sorted(_CONV_SHAPES))
@@ -353,6 +394,30 @@ def test_conv_matches_oracle_per_silo(case):
     paths = _conv_paths(jax.make_jaxpr(jax.vmap(
         lambda x, w: _conv(x, w, stride)))(x, w))
     assert paths == ((1, 0) if path == "native" else (0, 1))
+
+
+@pytest.mark.parametrize("precision", ["default", "highest"])
+def test_narrow_conv_matches_slice_im2col_bitwise(precision):
+    """FEMNIST `c1`'s shape (5x5, 1 -> 32, 28x28) under an 11-silo vmap:
+    the patch-extraction conv gives the slice-and-concatenate im2col's
+    output and filter gradient bit for bit, so the learned filter meets
+    the same operands in the same tap order."""
+    from repro.models.small import _conv
+    kx, kw_, kc = jax.random.split(jax.random.PRNGKey(11), 3)
+    x = jax.random.normal(kx, (11, 32, 28, 28, 1))
+    w = jax.random.normal(kw_, (11, 5, 5, 1, 32)) / 5.0
+    ct = jax.random.normal(kc, (11, 32, 28, 28, 32))
+
+    def run(conv):
+        def one(x, w, ct):
+            out, vjp = jax.vjp(lambda w: conv(x, w, 1), w)
+            return out, vjp(ct)[0]
+        return jax.jit(jax.vmap(one))(x, w, ct)
+
+    with jax.default_matmul_precision(precision):
+        got, want = run(_conv), run(_im2col_slices)
+    for name, g, r in zip(("out", "dw"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r), name)
 
 
 @pytest.mark.parametrize("name,native,im2col", [

@@ -134,12 +134,20 @@ def test_flat_cycle_compiles(shape, monkeypatch):
     assert all(flrt.SCOPE_AGGREGATE in ln for ln in kernel)
     assert flrt.SCOPE_LOCAL_SGD in text and flrt.SCOPE_REFRESH in text
     # the 32-channel conv is the chip's native convolution at the
-    # configured precision: no bf16 result, no (.., 14, 14, 800) patches
+    # configured precision: no learned-filter conv has a bf16 result, and
+    # there are no (.., 14, 14, 800) patches. The 1-channel conv's 25-wide
+    # patches come from one highest-precision patch-extraction conv (it
+    # may store bf16(x), which the default-precision dot rounds to
+    # anyway), not a concatenate
     convs = [ln for ln in text.splitlines() if " convolution(" in ln]
-    assert [ln for ln in convs if "conv_general_dilated" in ln]
-    assert not [ln for ln in convs if "= bf16[" in ln]
-    assert not [ln for ln in text.splitlines()
-                if " concatenate(" in ln and ",14,14,800]" in ln]
+    patch = [ln for ln in convs if "operand_precision={highest" in ln]
+    assert len(patch) == 1 and ",28,28,25]" in patch[0].split("=")[1]
+    learned = [ln for ln in convs if ln not in patch]
+    assert [ln for ln in learned if "conv_general_dilated" in ln]
+    assert not [ln for ln in learned if "= bf16[" in ln]
+    concats = [ln for ln in text.splitlines() if " concatenate(" in ln]
+    assert not [ln for ln in concats if ",14,14,800]" in ln]
+    assert not [ln for ln in concats if ",28,28,25]" in ln]
     mem = compiled.memory_analysis()
     # the state and edge buffers fit one v5e's 16 GB with room to spare
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
