@@ -33,8 +33,8 @@ axis — the sweep evaluates all 105 paper cells in max-transient vector
 steps (DESIGN.md §11).
 
 The dict-based `delay.MultigraphDelayTracker` is kept untouched as the
-equivalence oracle (the same way ``runtime="legacy"`` anchors the flat
-FL runtime); `tests/test_timing.py` asserts bit-for-bit agreement on
+equivalence oracle (the same way the per-round `fl/dpasgd.py` step
+anchors the flat FL runtime in the tests); `tests/test_timing.py` asserts bit-for-bit agreement on
 every paper network x workload over multiple cycles.
 """
 

@@ -36,7 +36,6 @@ from repro.design.search import (evolve_population, hill_climb,
                                  make_scorer, strong_fraction)
 from repro.faults import (DegradePolicy, FaultedSession, Scenario,
                           get_scenario)
-from repro.fl.options import RuntimeOptions, adopt_runtime_options
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,19 +71,19 @@ class ControllerConfig:
     replan_generations: int = 2
     replan_pop: int = 8
     replan_backend: str = "numpy"
-    # Shared runtime knobs (fl/options.py): mesh sharding (§16), gossip
-    # collective, metrics/trace. Pass one `RuntimeOptions` or the
-    # legacy kwargs; the live-swap contract is unchanged — swapped
-    # schedules are still just new runtime arguments to ONE traced
-    # cycle, a shard_map program under mesh.
-    options: RuntimeOptions | None = None
+    # Silo-axis device mesh (§16), as `FLConfig.mesh`; the live-swap
+    # contract is unchanged — swapped schedules are still just new
+    # runtime arguments to ONE traced cycle, a shard_map program
+    # under mesh.
     mesh: object = None
+    # Mesh only: cross-shard collective, "halo" or "all_gather".
     gossip: str = "halo"
+    # Rejected: in-scan metrics are FLConfig's (§17).
     metrics: object = None
+    # Path for a Perfetto trace-event JSON of each run; None = off.
     trace: str | None = None
 
     def __post_init__(self):
-        adopt_runtime_options(self)
         if self.metrics is not None:
             raise ValueError("ControllerConfig does not thread in-scan "
                              "metrics; use FLConfig(metrics=...) or the "
@@ -145,8 +144,9 @@ class ControllerHarness:
         from repro.data.synthetic import make_federated_dataset
         from repro.fl import dpasgd
         from repro.fl import flat as flatmod
+        from repro.fl import mesh as flmesh
         from repro.fl import runtime as flrt
-        from repro.fl.trainer import _DATASET_MODEL, _sample_round, FLConfig
+        from repro.fl.trainer import _DATASET_MODEL, _sample_round
         from repro.models.small import SMALL_MODELS
         from repro.networks.zoo import get_network
         from repro.optim import flat_sgd
@@ -175,41 +175,28 @@ class ControllerHarness:
         self._dpasgd = dpasgd
         self._flrt = flrt
         self._template = template
-        self.rt0 = flrt.make_flat_runtime(plan0, template, n)
-        if cfg.mesh is not None:
-            from repro.fl import mesh as flmesh
-            self.rt0 = flmesh.make_mesh_runtime(
-                self.rt0, None if cfg.mesh == "auto" else cfg.mesh)
-            self._cycle_fn = flrt.make_cycle_fn(
-                self.rt0, loss_fn=lambda p, b: self._spec.loss(p, b),
-                opt=self._opt, gossip=cfg.gossip)
-            self._init_state = lambda: flmesh.init_mesh_state(
-                self._spec.init, self._opt, self.rt0, self._key)
-            self._get_w = lambda st: jnp.asarray(
-                np.asarray(jax.device_get(st.w))[:n])
-        else:
-            self._cycle_fn = flrt.make_cycle_fn(
-                self.rt0, loss_fn=lambda p, b: self._spec.loss(p, b),
-                opt=self._opt)
-            self._init_state = lambda: flrt.init_flat_state(
-                self._spec.init, self._opt, self.rt0, self._key)
-            self._get_w = lambda st: st.w
+        self.rt0, _, self._rows = flmesh.place_runtime(
+            flrt.make_flat_runtime(plan0, template, n), cfg.mesh,
+            self._spec.init, self._opt, self._key)
+        self._cycle_fn = flrt.make_cycle_fn(
+            self.rt0, loss_fn=lambda p, b: self._spec.loss(p, b),
+            opt=self._opt, gossip=cfg.gossip
+            if isinstance(self.rt0, flmesh.MeshRuntime) else None)
+        # each run starts from a fresh state (the cycle donates it) on
+        # the runtime the cycle was built on
+        self._init_state = lambda: flmesh.place_runtime(
+            self.rt0, cfg.mesh, self._spec.init, self._opt, self._key)[1]
         self.density_floor = (cfg.density_slack
                               * strong_fraction(self.vec0) - 1e-12)
 
-        fl_cfg = FLConfig(dataset=self.dataset, network=cfg.network,
-                          topology="multigraph", rounds=cfg.rounds,
-                          eval_every=cfg.rounds, lr=cfg.lr,
-                          batch_size=cfg.batch_size,
-                          samples_per_silo=cfg.samples_per_silo,
-                          local_updates=cfg.local_updates, seed=cfg.seed)
         data = make_federated_dataset(self.dataset, n,
                                       samples_per_silo=cfg.samples_per_silo,
-                                      alpha=fl_cfg.alpha, seed=cfg.seed)
+                                      seed=cfg.seed)
         # Same draw order as trainer.run_fl / evaluate_frontier: runs
         # across the matrix consume the identical batch tensor.
         rng = np.random.default_rng(cfg.seed + 1)
-        per_round = [_sample_round(data, n, fl_cfg, rng)
+        per_round = [_sample_round(data, n, cfg.local_updates,
+                                   cfg.batch_size, rng)
                      for _ in range(cfg.rounds)]
         self._batches = {
             "x": jnp.asarray(np.stack([x for x, _ in per_round])),
@@ -314,7 +301,7 @@ class ControllerHarness:
 
         cfg = self.cfg
         sc = get_scenario(scenario) if isinstance(scenario, str) else scenario
-        # cfg.trace (RuntimeOptions) with no explicit recorder: record
+        # cfg.trace with no explicit recorder: record
         # this run and write the Perfetto trace on return
         auto_trace = recorder is None and cfg.trace is not None
         if auto_trace:
@@ -391,7 +378,7 @@ class ControllerHarness:
                             recorder.instant("swap", t_ms=sim_t,
                                              round=session.round,
                                              vector=list(vec))
-        acc = float(self._acc_fn(self._get_w(state)))
+        acc = float(self._acc_fn(self._rows(state)))
         if auto_trace:
             from repro.obs import write_trace
             write_trace(cfg.trace, recorder)

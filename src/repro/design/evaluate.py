@@ -131,7 +131,7 @@ def evaluate_frontier(network: str, workload: str, named_vectors, *,
     from repro.fl import dpasgd
     from repro.fl import flat as flatmod
     from repro.fl import runtime as flrt
-    from repro.fl.trainer import (_DATASET_MODEL, _sample_round, FLConfig)
+    from repro.fl.trainer import _DATASET_MODEL, _sample_round
     from repro.data.synthetic import make_federated_dataset
     from repro.models.small import SMALL_MODELS
     from repro.networks.zoo import get_network
@@ -143,15 +143,11 @@ def evaluate_frontier(network: str, workload: str, named_vectors, *,
     dataset = WL_TO_DATASET.get(workload, workload)
     n = net.num_silos
     spec = SMALL_MODELS[_DATASET_MODEL[dataset]]
-    cfg = FLConfig(dataset=dataset, network=network, topology="multigraph",
-                   rounds=rounds, eval_every=rounds, lr=lr,
-                   batch_size=batch_size, samples_per_silo=samples_per_silo,
-                   local_updates=local_updates, seed=seed)
     data = make_federated_dataset(dataset, n,
                                   samples_per_silo=samples_per_silo,
-                                  alpha=cfg.alpha, seed=seed)
+                                  seed=seed)
     key = jax.random.PRNGKey(seed)
-    opt = flat_sgd(lr, momentum=cfg.momentum)
+    opt = flat_sgd(lr)
     template = jax.eval_shape(spec.init, key)
     test_batch = {"x": jnp.asarray(data.test_x),
                   "y": jnp.asarray(data.test_y)}
@@ -182,7 +178,7 @@ def evaluate_frontier(network: str, workload: str, named_vectors, *,
                                            runtimes):
         t0 = time.perf_counter()
         rng = np.random.default_rng(seed + 1)
-        per_round = [_sample_round(data, n, cfg, rng)
+        per_round = [_sample_round(data, n, local_updates, batch_size, rng)
                      for _ in range(rounds)]
         batches = {"x": jnp.asarray(np.stack([x for x, _ in per_round])),
                    "y": jnp.asarray(np.stack([y for _, y in per_round]))}

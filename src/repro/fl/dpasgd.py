@@ -1,7 +1,9 @@
 """DPASGD with multigraph states (paper Eq. 2 / Eq. 6) — simulation mode.
 
 N silos live on one host as a stacked pytree (leading silo axis); every
-communication round is one jitted step:
+communication round is one jitted step (`fl_round_step`). The trainers
+run the flat whole-cycle runtime (fl/runtime.py); this per-round step is
+the tests' equivalence oracle for it. One round:
 
   1. u local SGD updates per silo (Eq. 2, lower branch) — vmap over the
      silo axis;

@@ -47,9 +47,9 @@ def pin_dtype(x: jax.Array, step: jax.Array) -> jax.Array:
 
     XLA:CPU lets LLVM contract ``a*b + c`` into an FMA, and whether it
     fires depends on how the surrounding computation was fused — the
-    flat `(N, T)` runtime and the legacy per-leaf pytree runtime got
+    flat `(N, T)` runtime and the per-leaf pytree oracle got
     DIFFERENT contractions for the momentum update, so the two drifted
-    by ulps (the one gap in the flat-vs-legacy bitwise equivalence).
+    by ulps (the one gap in the flat-vs-oracle bitwise equivalence).
     `jax.default_matmul_precision` only pins dot precision and the
     obvious barriers are erased before LLVM sees them
     (`lax.optimization_barrier` does not survive elementwise fusion,

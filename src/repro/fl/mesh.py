@@ -40,12 +40,14 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.fl import flat as flatmod
 from repro.fl import gossip
 from repro.fl.runtime import (SCOPE_AGGREGATE, SCOPE_LOCAL_SGD,
-                              SCOPE_REFRESH, FlatFLState, FlatRuntime)
+                              SCOPE_REFRESH, FlatFLState, FlatRuntime,
+                              init_flat_state)
 from repro.kernels.gossip_combine.ref import edge_aggregate_ref
 from repro.launch import mesh as meshmod
 from repro.launch.sharding import fl_plan_specs
@@ -226,11 +228,13 @@ def make_mesh_runtime(rt: FlatRuntime, mesh=None, *,
                       axis: str = meshmod.FL_AXIS) -> MeshRuntime:
     """Lay the runtime's CSR plan out over a silo-axis mesh, host-side.
 
-    ``mesh`` may be a Mesh, a shard count, or None (every device the
-    host exposes). All index tables — block bounds, pad edges, the halo
+    ``mesh`` may be a Mesh, a shard count, or None / "auto" (every
+    device the host exposes). All index tables — block bounds, pad edges, the halo
     exchange — are derived here ONCE; nothing about the layout depends
     on which schedule the cycle later runs.
     """
+    if mesh == "auto":
+        mesh = None
     if mesh is None or isinstance(mesh, int):
         mesh = meshmod.fl_mesh(mesh, axis=axis)
     assign = meshmod.silo_assignment(rt.num_silos, mesh, axis=axis)
@@ -259,6 +263,34 @@ def init_mesh_state(init_params: Callable[[jax.Array], Params], opt,
     opt_state = opt.init(w)
     buffers = w[jnp.asarray(mrt.src_global.reshape(-1))]
     return mrt.mspec.shard_tree(mrt.mesh, FlatFLState(w, opt_state, buffers))
+
+
+def place_runtime(rt: FlatRuntime | MeshRuntime, mesh,
+                  init_params: Callable[[jax.Array], Params], opt,
+                  key: jax.Array):
+    """Single device or silo mesh, decided in one place.
+
+    ``mesh`` is a trainer config's value: None keeps ``rt`` on one
+    device; an int, "auto" or a Mesh lays it out over the silo mesh
+    (`make_mesh_runtime`). A ``rt`` that is already a `MeshRuntime`
+    keeps its layout, which is how a caller re-initialises state on
+    the runtime it built its cycle on.
+
+    Returns ``(runtime, state, rows)``: the runtime to build the cycle
+    on, its initial state, and ``rows(state)``, the canonical ``(N, T)``
+    silo rows on the device. On the mesh that is ``w[:n]`` gathered to
+    every shard: pad rows sit at the global end of ``(Np, T)``, so it
+    equals `gather_flat_state`'s rows, bit for bit.
+    """
+    if mesh is None and not isinstance(rt, MeshRuntime):
+        return rt, init_flat_state(init_params, opt, rt, key), \
+            lambda st: st.w
+    mrt = rt if isinstance(rt, MeshRuntime) else make_mesh_runtime(rt, mesh)
+    n = mrt.num_silos
+    gather = jax.jit(lambda w: w[:n],
+                     out_shardings=NamedSharding(mrt.mesh, P()))
+    return mrt, init_mesh_state(init_params, opt, mrt, key), \
+        lambda st: gather(st.w)
 
 
 def gather_flat_state(mrt: MeshRuntime, state: FlatFLState) -> FlatFLState:

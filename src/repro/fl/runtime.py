@@ -1,8 +1,10 @@
 """Whole-cycle flat-parameter FL runtime (DESIGN.md §9).
 
-The legacy simulation (`fl/dpasgd.py`) dispatches one jitted step per
+The only FL runtime the trainers run. Its oracle, the per-round step
+over stacked pytrees in `fl/dpasgd.py`, dispatches one jitted step per
 communication round and aggregates with a per-leaf `segment_sum` over
-`(2E, ...)` buffers. This runtime removes both costs:
+`(2E, ...)` buffers; it lives on for the tests alone. This runtime
+removes both costs:
 
   * all N silo replicas live in ONE contiguous `(N, T)` fp32 buffer and
     the 2E directed-edge buffers in ONE `(2E, T)` buffer (repro/fl/flat),
@@ -14,8 +16,8 @@ communication round and aggregates with a per-leaf `segment_sum` over
     with the state donated, so a cycle has zero host round-trips and the
     cycle function traces/compiles exactly once for a given shape.
 
-Semantics are bit-for-bit fp32-identical to R calls of the legacy
-`fl_round_step` (tests/test_flat_runtime.py): the stable dst-sort keeps
+Semantics are bit-for-bit fp32-identical to R calls of that per-round
+oracle (tests/test_flat_runtime.py): the stable dst-sort keeps
 `segment_sum`'s accumulation order, and local SGD/refresh are the same
 elementwise ops on a packed layout.
 """
@@ -155,7 +157,7 @@ def make_cycle_fn(rt: FlatRuntime, *, loss_fn, opt, lr_scale=1.0,
 
     aggregator: "kernel" (Pallas `edge_aggregate`, interpret-mode off
     TPU), "reference" (`segment_sum` twin — bit-for-bit equal to the
-    legacy per-leaf lowering), or "dense" (uniform-in-degree overlays
+    oracle's per-leaf lowering), or "dense" (uniform-in-degree overlays
     only, e.g. any ring: reshapes the sorted buffers to (N, d, T) and
     reduces densely — no scatter, ~4x faster on XLA:CPU, same
     accumulation order up to FMA fusion). Default: kernel on TPU,
@@ -289,7 +291,7 @@ def make_cycle_fn(rt: FlatRuntime, *, loss_fn, opt, lr_scale=1.0,
 
 
 def unpack_params(rt: FlatRuntime, state: FlatFLState) -> Params:
-    """(N, T) -> stacked pytree with leading silo axis (legacy layout)."""
+    """(N, T) -> stacked pytree with leading silo axis (oracle layout)."""
     return flatmod.unravel_stacked(rt.spec, state.w)
 
 

@@ -4,37 +4,33 @@
 cycle-time simulator so results can be plotted against wall-clock time
 (paper Fig. 5).
 
-Two runtimes share one code path (`FLConfig.runtime`):
-
-  * "flat" (default) — the flat-parameter whole-cycle runtime
-    (repro/fl/runtime.py, DESIGN.md §9): params/opt-state/edge buffers
-    are packed `(N, T)`/`(2E, T)` arrays and a full multigraph cycle of
-    R rounds is ONE jitted dispatch (`lax.scan` over the RoundPlan
-    arrays). The training loop advances cycle-at-a-time; eval hooks
-    keep per-round granularity by splitting cycles at eval boundaries.
-  * "legacy" — one jitted `fl_round_step` dispatch per round over
-    stacked pytrees. Bit-for-bit fp32-identical learning curves
-    (momentum=0; see tests/test_flat_runtime.py), kept as the
-    equivalence oracle.
+Training runs on the flat-parameter whole-cycle runtime
+(repro/fl/runtime.py, DESIGN.md §9): params/opt-state/edge buffers are
+packed `(N, T)`/`(2E, T)` arrays and a full multigraph cycle of R
+rounds is ONE jitted dispatch (`lax.scan` over the RoundPlan arrays),
+on one device or sharded over a silo mesh (fl/mesh.py, §16). One
+driver (`_drive`) advances it cycle-at-a-time for both trainers, this
+module's `run_fl` and `launch/train.py`'s `run_reduced_fl`; eval and
+checkpoint hooks keep per-round granularity by splitting cycles at
+their boundaries. The per-round step of `fl/dpasgd.py` over stacked
+pytrees is the tests' oracle: the learning curves match it bit for
+bit (tests/test_flat_runtime.py).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.delay import WORKLOADS, Workload
-from repro.data.synthetic import FederatedDataset, make_federated_dataset
+from repro.data.synthetic import make_federated_dataset
 from repro.fl import dpasgd
-from repro.fl.options import RuntimeOptions, adopt_runtime_options
 from repro.models.small import SMALL_MODELS, SmallModelSpec
 from repro.networks.zoo import NetworkSpec, get_network
-from repro.optim import sgd
 
 _DATASET_MODEL = {"femnist": "femnist_cnn", "sent140": "sent140_lstm",
                   "inat": "inat_resnet"}
@@ -60,17 +56,15 @@ class FLConfig:
     # Table 4 ablation: remove silos from the RING overlay.
     remove_silos: int = 0
     remove_strategy: str = "none"  # none | random | inefficient
-    # "flat" = whole-cycle flat-parameter runtime; "legacy" = per-round
-    # stacked-pytree steps (kept as the equivalence oracle).
-    runtime: str = "flat"
-    # Shared runtime knobs (fl/options.py): mesh sharding (§16), gossip
-    # collective, in-scan metrics and trace output (§17). Either pass
-    # one `RuntimeOptions` here or keep using the legacy kwargs below —
-    # after construction the two views always agree.
-    options: RuntimeOptions | None = None
+    # Silo-axis device mesh (DESIGN.md §16): None = one device, an int
+    # = that many shards, "auto" = every host device, or a 1-D Mesh.
     mesh: object = None
+    # Mesh only: cross-shard source-row collective, "halo" (ppermute
+    # boundary exchange) or "all_gather".
     gossip: str = "halo"
+    # An `obs.MetricsSpec` compiled into the cycle scan (§17); None = off.
     metrics: object = None
+    # Path for a Perfetto trace-event JSON of the run; None = off.
     trace: str | None = None
     # Multigraph only: explicit multiplicity vector aligned with the
     # Christofides overlay pairs (the design search's exchange format);
@@ -80,14 +74,11 @@ class FLConfig:
     # on; every `ckpt_every` rounds (and at the final round) the
     # per-silo flat rows + run metadata land as a step-numbered FL
     # checkpoint the serving fleet can load. Under mesh sharding the
-    # rows are gathered through `gather_flat_state` first, so restores
-    # are bit-identical across device counts. Flat runtime only.
+    # canonical (N, T) rows are saved (`fl/mesh.py` `place_runtime`),
+    # so restores are bit-identical across device counts.
     ckpt_dir: str | None = None
     ckpt_every: int = 0
     ckpt_keep: int = 8
-
-    def __post_init__(self):
-        adopt_runtime_options(self)
 
 
 @dataclasses.dataclass
@@ -121,39 +112,120 @@ def _removed_network(net: NetworkSpec, wl: Workload, k: int,
     return removed_network(net, wl, k=k, strategy=strategy, seed=seed)
 
 
-def _sample_round(data, n: int, cfg: FLConfig, rng) -> tuple[np.ndarray,
-                                                             np.ndarray]:
-    """One round of micro batches, (u, N, b, ...) — the draw ORDER is
-    the contract: both runtimes consume the same rng stream identically,
-    so learning curves are comparable across `cfg.runtime`."""
+def _sample_round(data, n: int, local_updates: int, batch_size: int,
+                  rng) -> tuple[np.ndarray, np.ndarray]:
+    """One round of micro batches, (u, N, b, ...). The draw ORDER is
+    the contract: every trainer and the tests' per-round oracle consume
+    the same rng stream identically, so their curves are comparable."""
     xs, ys = [], []
-    for _ in range(cfg.local_updates):
-        per_silo = [data.sample_batch(s, cfg.batch_size, rng)
-                    for s in range(n)]
+    for _ in range(local_updates):
+        per_silo = [data.sample_batch(s, batch_size, rng) for s in range(n)]
         xs.append(np.stack([b["x"] for b in per_silo]))
         ys.append(np.stack([b["y"] for b in per_silo]))
     return np.stack(xs), np.stack(ys)
 
 
-def run_fl(cfg: FLConfig) -> FLResult:
-    """Train `cfg` end to end; returns losses, evals and the WAN axis.
+def _recorder(trace: str | None, **meta):
+    """A `TraceRecorder` carrying ``meta`` when ``trace`` is set."""
+    if not trace:
+        return None
+    from repro.obs import TraceRecorder
+    recorder = TraceRecorder()
+    recorder.meta.update(meta)
+    return recorder
 
-    With `cfg.trace` set (flat runtime), the loop records host spans
+
+@dataclasses.dataclass
+class _Log:
+    """What `_drive` has seen so far; hooks read it."""
+
+    losses: list[float] = dataclasses.field(default_factory=list)
+    eval_rounds: list[int] = dataclasses.field(default_factory=list)
+    eval_accs: list[float] = dataclasses.field(default_factory=list)
+    metrics: list[np.ndarray] = dataclasses.field(default_factory=list)
+
+
+def _drive(rt, state, cycle_fn, draw, *, rounds: int, recorder=None,
+           with_metrics: bool = False, eval_every: int = 0, evaluate=None,
+           ckpt_every: int = 0, checkpoint=None) -> _Log:
+    """Train ``rounds`` rounds, one compiled cycle dispatch at a time.
+
+    A dispatch runs up to a whole cycle (``rt.num_rounds_cycle``
+    rounds) and stops early at each eval boundary (every ``eval_every``
+    rounds, when ``evaluate`` is given) and checkpoint boundary (every
+    ``ckpt_every`` rounds, when ``checkpoint`` is given); both hooks
+    also run after the last round. ``draw(r)`` returns the host batch
+    arrays of the next ``r`` rounds, leaves ``(r, u, N, b, ...)``;
+    ``evaluate(state)`` returns an accuracy; ``checkpoint(k, state,
+    log)`` saves round ``k``.
+
+    With a ``recorder`` the loop records host spans
     (`obs.TraceRecorder.host_span`, DESIGN.md §17), per dispatch:
 
-        sample            draw each silo's batches, stack the chunk
+        sample            ``draw``: each silo's batches, stacked
         copy              host->device copy of the batches (`bytes`)
         compile+dispatch  the first dispatch; `dispatch` every later one
           launch          plan-slice copies and the compiled cycle's call
           sync            the loss (and metrics) sync to the host
-        eval              every `eval_every` rounds
-        checkpoint        when `ckpt_dir` is set
+        eval              at each eval boundary
+        checkpoint        at each checkpoint boundary
 
     `launch` and `sync` nest in the dispatch span (their `parent`); the
     others are top level. Each span is also a profiler annotation, and
     the compiled cycle splits its ops into the `fl.local_sgd`,
     `fl.refresh` and `fl.aggregate` scopes (fl/runtime.py), so a
     profile of the run puts host phases and device work on one clock.
+    """
+    span = (recorder.host_span if recorder is not None
+            else lambda name, **args: contextlib.nullcontext())
+    r_cycle = rt.num_rounds_cycle
+    log = _Log()
+    k = 0
+    while k < rounds:
+        next_stop = rounds
+        if evaluate is not None:
+            next_stop = min(next_stop, (k // eval_every + 1) * eval_every)
+        if checkpoint is not None and ckpt_every > 0:
+            next_stop = min(next_stop, (k // ckpt_every + 1) * ckpt_every)
+        chunk = min(r_cycle, next_stop - k)
+        with span("sample", rounds=chunk):
+            host = draw(chunk)
+        with span("copy", bytes=sum(a.nbytes for a in host.values())):
+            batches = {name: jnp.asarray(a) for name, a in host.items()}
+        pks = [(k + j) % r_cycle for j in range(chunk)]
+        with span("compile+dispatch" if k == 0 else "dispatch",
+                  start_round=k, rounds=chunk):
+            with span("launch", rounds=chunk):
+                out = cycle_fn(state, batches,
+                               jnp.asarray(rt.strong[pks]),
+                               jnp.asarray(rt.coeffs[pks]),
+                               jnp.asarray(rt.diag[pks]))
+            with span("sync"):
+                if with_metrics:
+                    state, losses, mets = out
+                    log.metrics.append(np.asarray(mets))
+                else:
+                    state, losses = out
+                losses = np.asarray(losses)
+        log.losses.extend(float(x) for x in losses)
+        k += chunk
+        if evaluate is not None and (k % eval_every == 0 or k == rounds):
+            with span("eval", round=k):
+                acc = evaluate(state)
+            log.eval_rounds.append(k)
+            log.eval_accs.append(acc)
+        if checkpoint is not None and (
+                k == rounds or (ckpt_every > 0 and k % ckpt_every == 0)):
+            with span("checkpoint", round=k):
+                checkpoint(k, state, log)
+    return log
+
+
+def run_fl(cfg: FLConfig) -> FLResult:
+    """Train `cfg` end to end; returns losses, evals and the WAN axis.
+
+    With `cfg.trace` set the run writes a Perfetto trace of the
+    driver's host spans (`_drive`) and the simulated WAN clock.
     """
     wl = WORKLOADS[_DATASET_WL[cfg.dataset]]
     net = get_network(cfg.network)
@@ -173,157 +245,61 @@ def run_fl(cfg: FLConfig) -> FLResult:
                                              rounds=cfg.rounds, seed=cfg.seed,
                                              multiplicity=cfg.multiplicity)
     key = jax.random.PRNGKey(cfg.seed)
-    loss_fn = lambda p, b: spec.loss(p, b)
     test_batch = {"x": jnp.asarray(data.test_x),
                   "y": jnp.asarray(data.test_y)}
     acc_fn = jax.jit(lambda p: spec.accuracy(p, test_batch))
+    recorder = _recorder(cfg.trace, dataset=cfg.dataset,
+                         network=cfg.network, topology=cfg.topology,
+                         rounds=cfg.rounds, seed=cfg.seed)
+
+    from repro.fl import flat as flatmod
+    from repro.fl import mesh as flmesh
+    from repro.fl import runtime as flrt
+    from repro.optim import flat_sgd
+    opt = flat_sgd(cfg.lr, momentum=cfg.momentum)
+    rt, state, rows = flmesh.place_runtime(
+        flrt.make_flat_runtime(plan, jax.eval_shape(spec.init, key), n),
+        cfg.mesh, spec.init, opt, key)
+    cycle_fn = flrt.make_cycle_fn(
+        rt, loss_fn=lambda p, b: spec.loss(p, b), opt=opt,
+        metrics=cfg.metrics,
+        gossip=cfg.gossip if isinstance(rt, flmesh.MeshRuntime) else None)
+    eval_params_fn = jax.jit(
+        lambda w: flatmod.unravel(rt.spec, jnp.mean(w, axis=0)))
 
     rng = np.random.default_rng(cfg.seed + 1)
-    r_cycle = plan.num_rounds_cycle
-    round_losses, eval_rounds, eval_accs = [], [], []
 
-    if (cfg.metrics is not None or cfg.trace) and cfg.runtime != "flat":
-        raise ValueError("metrics=/trace= need the flat whole-cycle "
-                         "runtime (the legacy path has no in-scan hook)")
-    if cfg.ckpt_dir and cfg.runtime != "flat":
-        raise ValueError("ckpt_dir= needs the flat runtime (the flat "
-                         "(N, T) rows ARE the checkpoint format)")
-    recorder = None
-    if cfg.trace:
-        from repro.obs import TraceRecorder
-        recorder = TraceRecorder()
-        recorder.meta.update(dataset=cfg.dataset, network=cfg.network,
-                             topology=cfg.topology, rounds=cfg.rounds,
-                             seed=cfg.seed)
-    metrics_chunks: list[np.ndarray] = []
+    def draw(chunk):
+        per_round = [_sample_round(data, n, cfg.local_updates,
+                                   cfg.batch_size, rng)
+                     for _ in range(chunk)]
+        return {"x": np.stack([x for x, _ in per_round]),
+                "y": np.stack([y for _, y in per_round])}
 
-    if cfg.runtime == "flat":
-        from repro.fl import flat as flatmod
-        from repro.fl import runtime as flrt
-        from repro.optim import flat_sgd
-        opt = flat_sgd(cfg.lr, momentum=cfg.momentum)
-        template = jax.eval_shape(spec.init, key)
-        rt = flrt.make_flat_runtime(plan, template, n)
-        if cfg.mesh is not None:
-            from repro.fl import mesh as flmesh
-            rt = flmesh.make_mesh_runtime(
-                rt, None if cfg.mesh == "auto" else cfg.mesh)
-            state = flmesh.init_mesh_state(spec.init, opt, rt, key)
-            cycle_fn = flrt.make_cycle_fn(rt, loss_fn=loss_fn, opt=opt,
-                                          gossip=cfg.gossip,
-                                          metrics=cfg.metrics)
-            # eval through the SAME single-device jit as mesh=None:
-            # silo rows are bit-identical, so accuracies are too
-            get_w = lambda st: jnp.asarray(
-                np.asarray(jax.device_get(st.w))[:n])
-        else:
-            state = flrt.init_flat_state(spec.init, opt, rt, key)
-            cycle_fn = flrt.make_cycle_fn(rt, loss_fn=loss_fn, opt=opt,
-                                          metrics=cfg.metrics)
-            get_w = lambda st: st.w
-        eval_params_fn = jax.jit(
-            lambda w: flatmod.unravel(rt.spec, jnp.mean(w, axis=0)))
+    checkpoint = None
+    if cfg.ckpt_dir:
+        from repro.checkpoint import CheckpointManager, save_fl_checkpoint
+        ckpt_mgr = CheckpointManager(cfg.ckpt_dir, keep=cfg.ckpt_keep)
+        cum_ms = np.cumsum(tplan.cycle_times(cfg.rounds))
 
-        ckpt_mgr = None
-        if cfg.ckpt_dir:
-            from repro.checkpoint import CheckpointManager, \
-                save_fl_checkpoint
-            ckpt_mgr = CheckpointManager(cfg.ckpt_dir, keep=cfg.ckpt_keep)
-            # the canonical (N, T) rows: a mesh run gathers through
-            # gather_flat_state so pad rows / block-padded edge layout
-            # never leak into the checkpoint — D=8 and D=1 runs save
+        def checkpoint(k, state, log):
+            # the canonical (N, T) rows: D=8 and D=1 runs save
             # bit-identical blocks (tests/test_serving_loop.py)
-            if cfg.mesh is not None:
-                ckpt_w = lambda st: flmesh.gather_flat_state(rt, st).w
-            else:
-                ckpt_w = lambda st: st.w
-            cum_ms = np.cumsum(tplan.cycle_times(cfg.rounds))
+            save_fl_checkpoint(
+                ckpt_mgr, k, rows(state),
+                round=k, network=cfg.network, dataset=cfg.dataset,
+                topology=cfg.topology, t=cfg.t, seed=cfg.seed,
+                num_silos=n, multiplicity=cfg.multiplicity,
+                lr=cfg.lr, momentum=cfg.momentum, alpha=cfg.alpha,
+                sim_time_ms=float(cum_ms[k - 1]) if k else 0.0,
+                loss_tail=[float(x) for x in log.losses[-8:]],
+                eval_accs=[float(x) for x in log.eval_accs[-4:]])
 
-            def emit_ckpt(k, state):
-                save_fl_checkpoint(
-                    ckpt_mgr, k, ckpt_w(state),
-                    round=k, network=cfg.network, dataset=cfg.dataset,
-                    topology=cfg.topology, t=cfg.t, seed=cfg.seed,
-                    num_silos=n, multiplicity=cfg.multiplicity,
-                    lr=cfg.lr, momentum=cfg.momentum,
-                    alpha=cfg.alpha,
-                    sim_time_ms=float(cum_ms[k - 1]) if k else 0.0,
-                    loss_tail=[float(x) for x in round_losses[-8:]],
-                    eval_accs=[float(x) for x in eval_accs[-4:]])
-
-        span = (recorder.host_span if recorder is not None
-                else lambda name, **args: contextlib.nullcontext())
-        k = 0
-        while k < cfg.rounds:
-            # advance a whole cycle per dispatch, splitting at eval
-            # boundaries so eval hooks keep per-round granularity
-            # (and at checkpoint boundaries when ckpt_every is set)
-            next_stop = min((k // cfg.eval_every + 1) * cfg.eval_every,
-                            cfg.rounds)
-            if ckpt_mgr is not None and cfg.ckpt_every > 0:
-                next_stop = min(next_stop,
-                                (k // cfg.ckpt_every + 1) * cfg.ckpt_every)
-            chunk = min(r_cycle, next_stop - k)
-            with span("sample", rounds=chunk):
-                per_round = [_sample_round(data, n, cfg, rng)
-                             for _ in range(chunk)]
-                xs = np.stack([x for x, _ in per_round])
-                ys = np.stack([y for _, y in per_round])
-            with span("copy", bytes=xs.nbytes + ys.nbytes):
-                batches = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
-            pks = [(k + j) % r_cycle for j in range(chunk)]
-            with span("compile+dispatch" if k == 0 else "dispatch",
-                      start_round=k, rounds=chunk):
-                with span("launch", rounds=chunk):
-                    out = cycle_fn(state, batches,
-                                   jnp.asarray(rt.strong[pks]),
-                                   jnp.asarray(rt.coeffs[pks]),
-                                   jnp.asarray(rt.diag[pks]))
-                with span("sync"):
-                    if cfg.metrics is not None:
-                        state, losses, mets = out
-                        metrics_chunks.append(np.asarray(mets))
-                    else:
-                        state, losses = out
-                    losses = np.asarray(losses)
-            round_losses.extend(float(x) for x in losses)
-            k += chunk
-            if k % cfg.eval_every == 0 or k == cfg.rounds:
-                with span("eval", round=k):
-                    acc = float(acc_fn(eval_params_fn(get_w(state))))
-                eval_rounds.append(k)
-                eval_accs.append(acc)
-            if ckpt_mgr is not None and (
-                    k == cfg.rounds or
-                    (cfg.ckpt_every > 0 and k % cfg.ckpt_every == 0)):
-                with span("checkpoint", round=k):
-                    emit_ckpt(k, state)
-    elif cfg.runtime == "legacy":
-        if cfg.mesh is not None:
-            raise ValueError("mesh= requires runtime='flat'")
-        opt = sgd(cfg.lr, momentum=cfg.momentum)
-        state = dpasgd.init_fl_state(spec.init, opt, n, plan.src, key)
-        step = jax.jit(lambda st, batches, s, c, d: dpasgd.fl_round_step(
-            st, batches, plan.src, plan.dst, s, c, d,
-            loss_fn=loss_fn, opt=opt, local_updates=cfg.local_updates))
-        eval_params_fn = jax.jit(
-            lambda w: jax.tree.map(lambda x: jnp.mean(x, axis=0), w))
-
-        for k in range(cfg.rounds):
-            xs, ys = _sample_round(data, n, cfg, rng)
-            batches = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
-            pk = k % r_cycle
-            state, loss = step(state, batches,
-                               jnp.asarray(plan.strong[pk]),
-                               jnp.asarray(plan.coeffs[pk]),
-                               jnp.asarray(plan.diag[pk]))
-            round_losses.append(float(loss))
-            if (k + 1) % cfg.eval_every == 0 or k == cfg.rounds - 1:
-                acc = float(acc_fn(eval_params_fn(state.silo_params)))
-                eval_rounds.append(k + 1)
-                eval_accs.append(acc)
-    else:
-        raise ValueError(f"unknown runtime {cfg.runtime!r}")
+    log = _drive(rt, state, cycle_fn, draw, rounds=cfg.rounds,
+                 recorder=recorder, with_metrics=cfg.metrics is not None,
+                 eval_every=cfg.eval_every,
+                 evaluate=lambda st: float(acc_fn(eval_params_fn(rows(st)))),
+                 ckpt_every=cfg.ckpt_every, checkpoint=checkpoint)
 
     # One TimingPlan, one report: the per-round axis comes from
     # `cycle_times` and the scalar totals from the SAME plan's
@@ -334,8 +310,7 @@ def run_fl(cfg: FLConfig) -> FLResult:
     # drifted apart for rounds > 512).
     cycle = tplan.cycle_times(cfg.rounds)
     rep = tplan.report(cfg.rounds)
-    all_metrics = (np.concatenate(metrics_chunks)
-                   if metrics_chunks else None)
+    all_metrics = np.concatenate(log.metrics) if log.metrics else None
     metric_cols = (getattr(cycle_fn, "metric_columns", ())
                    if cfg.metrics is not None else ())
     if recorder is not None:
@@ -345,8 +320,8 @@ def run_fl(cfg: FLConfig) -> FLResult:
             starts = np.concatenate([[0.0], np.cumsum(cycle)[:-1]])
             recorder.add_metrics(all_metrics, metric_cols, starts)
         write_trace(cfg.trace, recorder)
-    return FLResult(config=cfg, round_losses=round_losses,
-                    eval_rounds=eval_rounds, eval_accs=eval_accs,
+    return FLResult(config=cfg, round_losses=log.losses,
+                    eval_rounds=log.eval_rounds, eval_accs=log.eval_accs,
                     cycle_times_ms=cycle.tolist(),
                     mean_cycle_ms=rep.mean_cycle_ms,
                     total_time_s=rep.total_time_s,

@@ -2,8 +2,8 @@
 
 `gossip_combine_ref`: out = sum_k a[k] * w[k] (fixed-K stacked form).
 `edge_aggregate_ref`: the DPASGD aggregation over an arbitrary directed
-edge list via `segment_sum` — exactly the lowering `fl_round_step` uses
-per leaf, applied to one flat buffer. With its edges dst-sorted by a
+edge list via `segment_sum` — exactly the lowering the per-round
+oracle (`fl/dpasgd.py`) uses per leaf, applied to one flat buffer. With its edges dst-sorted by a
 stable sort, the CSR kernel sums in the same order and matches this to
 a few fp32 ulp (FMA contraction).
 """

@@ -22,24 +22,25 @@ Usage:
 from __future__ import annotations
 
 import argparse
-import contextlib
 import dataclasses
 import json
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduce as reduce_cfg
-from repro.core.delay import FEMNIST, MultigraphDelayTracker, WORKLOADS
+from repro.core.delay import FEMNIST, WORKLOADS
 from repro.data.synthetic import make_lm_dataset
 from repro.fl import dpasgd
-from repro.fl.options import RuntimeOptions, adopt_runtime_options
+from repro.fl import lora as loramod
+from repro.fl import mesh as flmesh
+from repro.fl import runtime as flrt
+from repro.fl.trainer import _drive, _recorder
 from repro.models import transformer as tf
-from repro.models.frontends import prefix_tokens, synthetic_prefix
+from repro.models.frontends import synthetic_prefix
 from repro.networks.zoo import NetworkSpec, get_network
-from repro.optim import adamw, sgd
+from repro.optim import flat_sgd
 
 
 def _sub_network(net: NetworkSpec, n: int) -> NetworkSpec:
@@ -62,17 +63,17 @@ class TrainConfig:
     lr: float = 3e-3
     seed: int = 0
     reduced: bool = True
-    # Shared runtime knobs (fl/options.py): mesh sharding (None =
-    # legacy per-round runtime; an int / "auto" / a Mesh runs the
-    # whole-cycle flat runtime, DESIGN.md §16), gossip collective, and
-    # trace output. Pass one `RuntimeOptions` or the legacy kwargs.
-    options: RuntimeOptions | None = None
+    # Silo-axis device mesh (DESIGN.md §16): None = one device, an int
+    # = that many shards, "auto" = every host device, or a 1-D Mesh.
     mesh: object = None
+    # Mesh only: cross-shard collective, "halo" or "all_gather".
     gossip: str = "halo"
+    # Rejected: in-scan metrics are FLConfig's (§17).
     metrics: object = None
+    # Path for a Perfetto trace-event JSON of the run; None = off.
     trace: str | None = None
-    # Mesh path only: rank > 0 trains LoRA deltas over a frozen shared
-    # base (fl/lora.py) so per-silo state is T_lora, not T_full.
+    # rank > 0 trains LoRA deltas over a frozen shared base
+    # (fl/lora.py) so per-silo state is T_lora, not T_full.
     lora_rank: int = 0
     # Periodic FL checkpoints (checkpoint/ckpt.py): per-silo flat rows
     # (the LoRA delta rows when lora_rank > 0) + metadata every
@@ -82,7 +83,6 @@ class TrainConfig:
     ckpt_keep: int = 8
 
     def __post_init__(self):
-        adopt_runtime_options(self)
         if self.metrics is not None:
             raise ValueError("TrainConfig does not thread in-scan "
                              "metrics; use FLConfig(metrics=...)")
@@ -96,20 +96,15 @@ def run_reduced_fl(cfg: TrainConfig) -> dict:
 
     plan, tplan = dpasgd.make_round_schedule(cfg.topology, net, wl, t=cfg.t,
                                              rounds=cfg.rounds, seed=cfg.seed)
-    recorder = None
-    if cfg.trace:
-        from repro.obs import TraceRecorder
-        recorder = TraceRecorder()
-        recorder.meta.update(arch=cfg.arch, topology=cfg.topology,
-                             network=net.name, rounds=cfg.rounds,
-                             seed=cfg.seed)
+    recorder = _recorder(cfg.trace, arch=cfg.arch, topology=cfg.topology,
+                         network=net.name, rounds=cfg.rounds, seed=cfg.seed)
     key = jax.random.PRNGKey(cfg.seed)
     data = make_lm_dataset(mcfg.vocab_size, cfg.seq_len, n,
                            samples_per_silo=64, seed=cfg.seed)
     prefix = None
     if mcfg.frontend != "none":
-        prefix = jnp.stack([synthetic_prefix(mcfg, cfg.batch_size, seed=s)
-                            for s in range(n)])[None]  # (1, N, B, P, D)
+        prefix = np.stack([synthetic_prefix(mcfg, cfg.batch_size, seed=s)
+                           for s in range(n)])[None]  # (1, N, B, P, D)
 
     def loss_fn(p, batch):
         b = {"tokens": batch["tokens"], "labels": batch["labels"]}
@@ -120,29 +115,44 @@ def run_reduced_fl(cfg: TrainConfig) -> dict:
 
     rng = np.random.default_rng(cfg.seed)
 
-    def draw_round():
-        toks = np.stack([
+    def draw(chunk):
+        toks = np.stack([np.stack([
             data[s][rng.integers(0, len(data[s]), cfg.batch_size)]
-            for s in range(n)])  # (N, B, S+1)
-        return toks
+            for s in range(n)]) for _ in range(chunk)])  # (r, N, B, S+1)
+        batches = {"tokens": toks[:, None, :, :, :-1],
+                   "labels": toks[:, None, :, :, 1:]}
+        if prefix is not None:
+            batches["prefix_embeds"] = np.broadcast_to(
+                prefix[None], (chunk,) + prefix.shape)
+        return batches
 
-    losses = []
-    r_cycle = plan.num_rounds_cycle
     t0 = time.time()
-    ckpt_mgr = None
-    ckpt_w = None  # set per-path: state -> gathered (N, T) flat rows
+    # with lora_rank > 0 the trainable per-silo state is the LoRA delta
+    # over a frozen base shared by every silo (fl/lora.py)
+    init_fn = lambda k: tf.init_params(mcfg, k)
+    cycle_loss = loss_fn
+    if cfg.lora_rank > 0:
+        base = tf.init_params(mcfg, jax.random.PRNGKey(cfg.seed + 1))
+        adapter = loramod.make_lora_adapter(base, cfg.lora_rank)
+        init_fn = adapter.init
+        cycle_loss = adapter.wrap_loss(loss_fn)
+    opt = flat_sgd(cfg.lr, momentum=0.9)
+    rt, state, rows = flmesh.place_runtime(
+        flrt.make_flat_runtime(plan, jax.eval_shape(init_fn, key), n),
+        cfg.mesh, init_fn, opt, key)
+    cycle = flrt.make_cycle_fn(
+        rt, loss_fn=cycle_loss, opt=opt,
+        gossip=cfg.gossip if isinstance(rt, flmesh.MeshRuntime) else None)
+
+    checkpoint = None
     if cfg.ckpt_dir:
-        from repro.checkpoint import CheckpointManager
+        from repro.checkpoint import CheckpointManager, save_fl_checkpoint
         ckpt_mgr = CheckpointManager(cfg.ckpt_dir, keep=cfg.ckpt_keep)
         ckpt_cum_ms = np.cumsum(tplan.cycle_times(cfg.rounds))
 
-    def emit_ckpt(k, state):
-        from repro.checkpoint import save_fl_checkpoint
-        span = (recorder.host_span("checkpoint", round=k)
-                if recorder is not None else contextlib.nullcontext())
-        with span:
+        def checkpoint(k, state, log):
             save_fl_checkpoint(
-                ckpt_mgr, k, ckpt_w(state),
+                ckpt_mgr, k, rows(state),
                 round=k, arch=cfg.arch, network=cfg.network,
                 dataset="synthetic-lm", workload="femnist",
                 topology=cfg.topology, t=cfg.t, seed=cfg.seed,
@@ -150,107 +160,14 @@ def run_reduced_fl(cfg: TrainConfig) -> dict:
                 params_kind="lora_delta" if cfg.lora_rank else "full",
                 seq_len=cfg.seq_len, lr=cfg.lr,
                 sim_time_ms=float(ckpt_cum_ms[k - 1]) if k else 0.0,
-                loss_tail=[float(x) for x in losses[-8:]])
+                loss_tail=[float(x) for x in log.losses[-8:]])
 
-    if cfg.mesh is not None:
-        # mesh-sharded whole-cycle flat runtime (DESIGN.md §16); with
-        # lora_rank > 0 the trainable per-silo state is the LoRA delta
-        # over a frozen base shared by every silo (fl/lora.py)
-        from repro.fl import lora as loramod
-        from repro.fl import mesh as flmesh
-        from repro.fl import runtime as flrt
-        from repro.optim import flat_sgd
-        init_fn = lambda k: tf.init_params(mcfg, k)
-        cycle_loss = loss_fn
-        if cfg.lora_rank > 0:
-            base = tf.init_params(mcfg, jax.random.PRNGKey(cfg.seed + 1))
-            adapter = loramod.make_lora_adapter(base, cfg.lora_rank)
-            init_fn = adapter.init
-            cycle_loss = adapter.wrap_loss(loss_fn)
-        opt = flat_sgd(cfg.lr, momentum=0.9)
-        rt = flrt.make_flat_runtime(plan, jax.eval_shape(init_fn, key), n)
-        mrt = flmesh.make_mesh_runtime(
-            rt, None if cfg.mesh == "auto" else cfg.mesh)
-        state = flmesh.init_mesh_state(init_fn, opt, mrt, key)
-        cycle = flrt.make_cycle_fn(mrt, loss_fn=cycle_loss, opt=opt,
-                                   gossip=cfg.gossip)
-        if ckpt_mgr is not None:
-            # canonical single-device layout: drop pad rows, restore
-            # dst-sorted edge order (DESIGN.md §16) so a D=8 run's
-            # checkpoint is bit-identical to the D=1 run's
-            ckpt_w = lambda st: flmesh.gather_flat_state(mrt, st).w
-        k = 0
-        while k < cfg.rounds:
-            chunk = min(r_cycle, cfg.rounds - k)
-            if ckpt_mgr is not None and cfg.ckpt_every > 0:
-                nxt = (k // cfg.ckpt_every + 1) * cfg.ckpt_every
-                chunk = min(chunk, nxt - k)
-            toks = np.stack([draw_round() for _ in range(chunk)])
-            batches = {"tokens": jnp.asarray(toks[:, None, :, :, :-1]),
-                       "labels": jnp.asarray(toks[:, None, :, :, 1:])}
-            if prefix is not None:
-                batches["prefix_embeds"] = jnp.broadcast_to(
-                    prefix[None], (chunk,) + prefix.shape)
-            pks = [(k + j) % r_cycle for j in range(chunk)]
-            span = (recorder.host_span(
-                        "compile+dispatch" if k == 0 else "dispatch",
-                        start_round=k, rounds=chunk)
-                    if recorder is not None else contextlib.nullcontext())
-            with span:
-                state, chunk_losses = cycle(state, batches,
-                                            jnp.asarray(rt.strong[pks]),
-                                            jnp.asarray(rt.coeffs[pks]),
-                                            jnp.asarray(rt.diag[pks]))
-                chunk_losses = np.asarray(chunk_losses)
-            losses.extend(float(x) for x in chunk_losses)
-            k += chunk
-            if ckpt_mgr is not None and (
-                    k == cfg.rounds or
-                    (cfg.ckpt_every > 0 and k % cfg.ckpt_every == 0)):
-                emit_ckpt(k, state)
-        # bytes a silo actually communicates per round: the flat row
-        # (the LoRA delta when lora_rank > 0, not the frozen base)
-        param_bytes = rt.spec.size * 4
-    else:
-        if cfg.lora_rank:
-            raise ValueError("lora_rank requires the mesh runtime "
-                             "(set mesh=, e.g. mesh='auto')")
-        opt = sgd(cfg.lr, momentum=0.9)
-        state = dpasgd.init_fl_state(lambda k: tf.init_params(mcfg, k), opt,
-                                     n, plan.src, key)
-        step = jax.jit(lambda st, batches, s, c, d: dpasgd.fl_round_step(
-            st, batches, plan.src, plan.dst, s, c, d,
-            loss_fn=loss_fn, opt=opt, local_updates=1))
-        if ckpt_mgr is not None:
-            from repro.fl import flat as flatmod
-            ckpt_spec = flatmod.make_flat_spec(
-                jax.eval_shape(lambda kk: tf.init_params(mcfg, kk), key))
-            ckpt_w = lambda st: flatmod.ravel_stacked(ckpt_spec,
-                                                      st.silo_params)
-        for k in range(cfg.rounds):
-            toks = draw_round()
-            batches = {"tokens": jnp.asarray(toks[None, :, :, :-1]),
-                       "labels": jnp.asarray(toks[None, :, :, 1:])}
-            if prefix is not None:
-                batches["prefix_embeds"] = prefix
-            pk = k % r_cycle
-            span = (recorder.host_span(
-                        "compile+dispatch" if k == 0 else "dispatch",
-                        start_round=k, rounds=1)
-                    if recorder is not None else contextlib.nullcontext())
-            with span:
-                state, loss = step(state, batches,
-                                   jnp.asarray(plan.strong[pk]),
-                                   jnp.asarray(plan.coeffs[pk]),
-                                   jnp.asarray(plan.diag[pk]))
-                loss = float(loss)
-            losses.append(loss)
-            if ckpt_mgr is not None and (
-                    k + 1 == cfg.rounds or
-                    (cfg.ckpt_every > 0 and (k + 1) % cfg.ckpt_every == 0)):
-                emit_ckpt(k + 1, state)
-        param_bytes = sum(x.size * x.dtype.itemsize
-                          for x in jax.tree.leaves(state.silo_params)) / n
+    losses = _drive(rt, state, cycle, draw, rounds=cfg.rounds,
+                    recorder=recorder, ckpt_every=cfg.ckpt_every,
+                    checkpoint=checkpoint).losses
+    # bytes a silo actually communicates per round: the flat row
+    # (the LoRA delta when lora_rank > 0, not the frozen base)
+    param_bytes = rt.spec.size * 4
 
     # simulated wall-clock (model-size-aware workload)
     wl_model = dataclasses.replace(
@@ -268,7 +185,7 @@ def run_reduced_fl(cfg: TrainConfig) -> dict:
         "sim_mean_cycle_ms": sim.mean_cycle_ms,
         "sim_total_time_s": sim.total_time_s,
     }
-    if ckpt_mgr is not None:
+    if cfg.ckpt_dir:
         out["ckpt_dir"] = str(ckpt_mgr.dir)
         out["ckpt_steps"] = ckpt_mgr.steps()
     if recorder is not None:
@@ -291,8 +208,8 @@ def main():
     ap.add_argument("--batch-size", type=int, default=4)
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--mesh", default=None,
-                    help="silo shards: an int, 'auto', or unset for the "
-                         "legacy per-round runtime")
+                    help="silo shards: an int, 'auto', or unset for one "
+                         "device")
     ap.add_argument("--lora-rank", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None,
                     help="emit FL checkpoints (per-silo flat rows + "

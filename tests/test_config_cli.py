@@ -23,6 +23,9 @@ def test_override_bool_and_unknown():
         apply_overrides(TrainConfig(), ["nope=1"])
     with pytest.raises(OverrideError, match="key=value"):
         apply_overrides(TrainConfig(), ["oops"])
+    # in-scan metrics are FLConfig's; the LM trainer refuses them
+    with pytest.raises(ValueError, match="metrics"):
+        TrainConfig(metrics=object())
 
 
 def test_override_model_config_literal():

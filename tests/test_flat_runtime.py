@@ -6,14 +6,15 @@ CSR edge-aggregation kernel, and the trainer's whole-cycle path):
   * CSR `edge_aggregate` == per-destination `segment_sum` oracle on
     random graphs with random degrees INCLUDING isolated destinations
     (zero incoming edges — the paper's isolated-node mechanism);
-  * one flat-runtime cycle == R jitted legacy `fl_round_step` calls,
+  * one flat-runtime cycle == R jitted `dpasgd.fl_round_step` calls
+    (the per-round oracle over stacked pytrees),
     bit-for-bit in fp32 INCLUDING momentum (the optimizers pin the FMA
     contraction of `momentum*mu + g` / `w - lr*mu` via fl/flat.py's
     `pin_f32`, so packed and per-leaf layouts compute identical bits);
   * a full multigraph cycle is ONE compiled dispatch: the cycle
     function traces exactly once across repeated cycles;
   * flat_sgd == vmapped per-silo sgd;
-  * run_fl(runtime="flat") == run_fl(runtime="legacy") end-to-end.
+  * run_fl == a per-round `fl_round_step` loop end-to-end.
 """
 
 import numpy as np
@@ -159,7 +160,7 @@ def test_edge_aggregate_degenerate_shapes():
 
 
 # ---------------------------------------------------------------------------
-# whole-cycle equivalence vs legacy fl_round_step
+# whole-cycle equivalence vs the per-round fl_round_step oracle
 # ---------------------------------------------------------------------------
 
 
@@ -284,21 +285,86 @@ def test_flat_sgd_matches_vmapped_sgd():
 
 
 # ---------------------------------------------------------------------------
-# trainer end-to-end: flat == legacy
+# trainer end-to-end: run_fl == a per-round fl_round_step loop
 # ---------------------------------------------------------------------------
+
+
+def _run_fl_per_round(cfg):
+    """`run_fl`'s semantics one round a dispatch on the stacked-pytree
+    oracle: same data, schedule, init and `_sample_round` draws, and the
+    mean of the silo params evaluated after every `eval_every` rounds.
+    Returns (round_losses, eval_rounds, eval_accs, cycle_times_ms)."""
+    from repro.data.synthetic import make_federated_dataset
+    from repro.fl.trainer import (WORKLOADS, _DATASET_MODEL, _DATASET_WL,
+                                  _sample_round)
+    from repro.models.small import SMALL_MODELS
+    net = get_network(cfg.network)
+    n = net.num_silos
+    spec = SMALL_MODELS[_DATASET_MODEL[cfg.dataset]]
+    data = make_federated_dataset(cfg.dataset, n,
+                                  samples_per_silo=cfg.samples_per_silo,
+                                  alpha=cfg.alpha, seed=cfg.seed)
+    plan, tplan = dpasgd.make_round_schedule(
+        cfg.topology, net, WORKLOADS[_DATASET_WL[cfg.dataset]], t=cfg.t,
+        rounds=cfg.rounds, seed=cfg.seed)
+    test_batch = {"x": jnp.asarray(data.test_x),
+                  "y": jnp.asarray(data.test_y)}
+    acc_fn = jax.jit(lambda p: spec.accuracy(p, test_batch))
+    mean_params = jax.jit(
+        lambda w: jax.tree.map(lambda x: jnp.mean(x, axis=0), w))
+    opt = sgd(cfg.lr, momentum=cfg.momentum)
+    state = dpasgd.init_fl_state(spec.init, opt, n, plan.src,
+                                 jax.random.PRNGKey(cfg.seed))
+    step = jax.jit(lambda st, b, s, c, d: dpasgd.fl_round_step(
+        st, b, plan.src, plan.dst, s, c, d,
+        loss_fn=lambda p, bb: spec.loss(p, bb), opt=opt,
+        local_updates=cfg.local_updates))
+    rng = np.random.default_rng(cfg.seed + 1)
+    losses, eval_rounds, eval_accs = [], [], []
+    for k in range(cfg.rounds):
+        xs, ys = _sample_round(data, n, cfg.local_updates, cfg.batch_size,
+                               rng)
+        pk = k % plan.num_rounds_cycle
+        state, loss = step(state, {"x": jnp.asarray(xs),
+                                   "y": jnp.asarray(ys)},
+                           jnp.asarray(plan.strong[pk]),
+                           jnp.asarray(plan.coeffs[pk]),
+                           jnp.asarray(plan.diag[pk]))
+        losses.append(float(loss))
+        if (k + 1) % cfg.eval_every == 0 or k + 1 == cfg.rounds:
+            eval_rounds.append(k + 1)
+            eval_accs.append(float(acc_fn(mean_params(state.silo_params))))
+    return (losses, eval_rounds, eval_accs,
+            tplan.cycle_times(cfg.rounds).tolist())
+
+
+@pytest.mark.parametrize("topology", ["ring", "multigraph"])
+def test_run_fl_matches_per_round_oracle(topology):
+    """The whole-cycle trainer reproduces the per-round oracle's
+    learning curve and accuracies exactly (ring: one round a dispatch;
+    multigraph: cycles split at the eval boundary)."""
+    from repro.fl.trainer import FLConfig, run_fl
+    cfg = FLConfig(dataset="femnist", network="gaia", topology=topology,
+                   rounds=4, eval_every=2, samples_per_silo=16,
+                   batch_size=4, lr=0.05, seed=3)
+    res = run_fl(cfg)
+    losses, eval_rounds, eval_accs, _ = _run_fl_per_round(cfg)
+    assert res.round_losses == losses
+    assert res.eval_rounds == eval_rounds == [2, 4]
+    assert res.eval_accs == eval_accs
 
 
 @pytest.mark.slow
 def test_trainer_flat_matches_legacy():
     from repro.fl.trainer import FLConfig, run_fl
-    base = dict(dataset="femnist", network="gaia", topology="multigraph",
-                rounds=4, eval_every=2, samples_per_silo=16, batch_size=4,
-                lr=0.05, seed=3)
-    flat = run_fl(FLConfig(runtime="flat", **base))
-    legacy = run_fl(FLConfig(runtime="legacy", **base))
-    assert flat.round_losses == legacy.round_losses
-    assert flat.eval_rounds == legacy.eval_rounds
-    assert flat.eval_accs == legacy.eval_accs
+    cfg = FLConfig(dataset="femnist", network="gaia", topology="multigraph",
+                   rounds=4, eval_every=2, samples_per_silo=16, batch_size=4,
+                   lr=0.05, seed=3)
+    flat = run_fl(cfg)
+    losses, eval_rounds, eval_accs, _ = _run_fl_per_round(cfg)
+    assert flat.round_losses == losses
+    assert flat.eval_rounds == eval_rounds
+    assert flat.eval_accs == eval_accs
 
 
 @pytest.mark.slow
@@ -308,16 +374,16 @@ def test_trainer_flat_matches_legacy_momentum():
     pinned (fl/flat.py `pin_f32`), so the packed and per-leaf layouts
     produce identical curves — no ulp allowance anymore."""
     from repro.fl.trainer import FLConfig, run_fl
-    base = dict(dataset="femnist", network="gaia", topology="multigraph",
-                rounds=4, eval_every=2, samples_per_silo=16, batch_size=4,
-                lr=0.05, momentum=0.9, seed=5)
-    flat = run_fl(FLConfig(runtime="flat", **base))
-    legacy = run_fl(FLConfig(runtime="legacy", **base))
-    assert flat.round_losses == legacy.round_losses
-    assert flat.eval_rounds == legacy.eval_rounds
-    assert flat.eval_accs == legacy.eval_accs
-    # both runtimes share the same TimingPlan wall-clock axis exactly
-    assert flat.cycle_times_ms == legacy.cycle_times_ms
+    cfg = FLConfig(dataset="femnist", network="gaia", topology="multigraph",
+                   rounds=4, eval_every=2, samples_per_silo=16, batch_size=4,
+                   lr=0.05, momentum=0.9, seed=5)
+    flat = run_fl(cfg)
+    losses, eval_rounds, eval_accs, cycle_times_ms = _run_fl_per_round(cfg)
+    assert flat.round_losses == losses
+    assert flat.eval_rounds == eval_rounds
+    assert flat.eval_accs == eval_accs
+    # the trainer and the oracle share the same TimingPlan wall-clock axis
+    assert flat.cycle_times_ms == cycle_times_ms
 
 
 # ---------------------------------------------------------------------------

@@ -534,8 +534,3 @@ for backend in ("halo", "all_gather"):
     assert "halo-scopes-ok" in r.stdout and "all_gather-scopes-ok" \
         in r.stdout, r.stdout
 
-
-def test_trainer_rejects_obs_on_legacy_runtime():
-    from repro.fl.trainer import FLConfig, run_fl
-    with pytest.raises(ValueError, match="flat"):
-        run_fl(FLConfig(runtime="legacy", metrics=MetricsSpec(), rounds=2))

@@ -1,7 +1,7 @@
 """Train -> checkpoint -> regional fleet -> traffic loop (DESIGN.md
-§18) plus the API-redesign seams it rides on: the network registry,
-the unified RuntimeOptions embedding, and the FL-checkpoint format's
-mesh/single-device round-trip contract.
+§18) plus the seams it rides on: the network registry, the LM
+trainer's host spans, and the FL-checkpoint format's mesh/single-device
+round-trip contract.
 
 One tiny reduced-LM FL run (module-scoped fixture) feeds every fleet
 test; the D=8 sharded round-trip runs in a subprocess with forced
@@ -44,7 +44,7 @@ def fleet(ckpt_dir):
 
 
 # ---------------------------------------------------------------------------
-# satellite seams: registry + options
+# satellite seams: registry
 # ---------------------------------------------------------------------------
 
 class TestNetworkRegistry:
@@ -71,37 +71,54 @@ class TestNetworkRegistry:
         np.testing.assert_array_equal(old.latency_ms, new.latency_ms)
 
 
-class TestRuntimeOptions:
-    def test_flconfig_embedding(self):
-        from repro.fl.options import RuntimeOptions
-        from repro.fl.trainer import FLConfig
-        c = FLConfig(options=RuntimeOptions(mesh=2, gossip="all_gather"))
-        assert c.mesh == 2 and c.gossip == "all_gather"
-
-    def test_legacy_kwarg_wins(self):
-        from repro.fl.options import RuntimeOptions
-        from repro.fl.trainer import FLConfig
-        c = FLConfig(options=RuntimeOptions(gossip="all_gather"),
-                     gossip="matmul")
-        assert c.gossip == "matmul"
-        assert c.options.gossip == "matmul"  # canonical rebuilt
-
-    def test_controller_and_train_configs(self):
-        from repro.design.controller import ControllerConfig
-        from repro.fl.options import RuntimeOptions
-        cc = ControllerConfig(options=RuntimeOptions(mesh="auto"))
-        assert cc.mesh == "auto"
-        tc = TrainConfig(options=RuntimeOptions(mesh=4))
-        assert tc.mesh == 4
-        with pytest.raises(ValueError, match="metrics"):
-            TrainConfig(metrics=object())
-
-
 # ---------------------------------------------------------------------------
 # checkpoint round-trip
 # ---------------------------------------------------------------------------
 
-def test_checkpoint_legacy_vs_mesh_bitexact(tmp_path):
+@pytest.mark.parametrize("mesh", [None, 1])
+def test_run_reduced_fl_host_spans_per_dispatch(tmp_path, mesh):
+    """`run_reduced_fl` trains through `run_fl`'s driver on one device
+    and on the mesh alike: the same host spans, one set a dispatch."""
+    import repro.obs as obs
+    kept = []
+
+    class Kept(obs.TraceRecorder):
+        def __post_init__(self):
+            super().__post_init__()
+            kept.append(self)
+
+    real, obs.TraceRecorder = obs.TraceRecorder, Kept
+    try:
+        run_reduced_fl(TrainConfig(**dict(TINY, topology="ring", rounds=3),
+                                   mesh=mesh,
+                                   trace=str(tmp_path / "t.json")))
+    finally:
+        obs.TraceRecorder = real
+    (rec,) = kept
+    ev = rec.host_events
+    end = lambda e: e["t0_ms"] + e["dur_ms"]
+    disp = [e for e in ev if e["name"] in ("compile+dispatch", "dispatch")]
+    assert [e["name"] for e in disp] == ["compile+dispatch"] + ["dispatch"] * 2
+    for name in ("sample", "copy", "launch", "sync"):
+        assert len([e for e in ev if e["name"] == name]) == len(disp), name
+    for d in disp:
+        # the jit call and the loss sync, in order, inside the dispatch
+        launch, sync = [e for e in ev if e["parent"] == d["name"]
+                        and d["t0_ms"] <= e["t0_ms"] and end(e) <= end(d)]
+        assert (launch["name"], sync["name"]) == ("launch", "sync")
+        assert end(launch) <= sync["t0_ms"]
+        assert launch["args"] == {"rounds": 1}
+    samples = [e for e in ev if e["name"] == "sample"]
+    copies = [e for e in ev if e["name"] == "copy"]
+    for s, c, d in zip(samples, copies, disp):
+        assert s["parent"] is None and c["parent"] is None
+        assert end(s) <= c["t0_ms"] and end(c) <= d["t0_ms"]
+        assert s["args"] == {"rounds": 1}
+        # one round of 6 silos x 2 sequences: int32 tokens and labels
+        assert c["args"] == {"bytes": 2 * 6 * 2 * TINY["seq_len"] * 4}
+
+
+def test_checkpoint_single_device_vs_mesh_bitexact(tmp_path):
     cfg = dict(TINY, rounds=2)
     run_reduced_fl(TrainConfig(**cfg, ckpt_dir=str(tmp_path / "a")))
     run_reduced_fl(TrainConfig(**cfg, mesh=1,
